@@ -39,6 +39,8 @@ def main() -> None:
     if args.sanitize:
         import os
         os.environ["REPRO_SANITIZE"] = "1"
+    from repro.kernels.platform import enable_compile_cache
+    enable_compile_cache()
 
     from . import fig_benchmarks as fb
     names = args.only.split(",") if args.only else list(fb.ALL)
@@ -57,101 +59,82 @@ def main() -> None:
         print(f"# {name} done in {time.time()-t1:.1f}s", flush=True)
     # db_bench (paper §5: amplification-only, Meta-style population).
     # Policies resolve from the registry: --policy vlsm,lazy or 'all'.
-    try:
-        from repro.bench_kv.db_bench import chain_report, fill_sim, fillrandom
-        from repro.core.policies import get_policy, resolve_names
-        from .common import SCALE, emit
-        chosen = resolve_names(args.policy)
-        for dist in ("uniform", "pareto"):
-            for nm in chosen:
-                cfg = get_policy(nm).default_config(scale=SCALE)
-                run = fill_sim(cfg, 60_000, dist, SCALE, args.seed)
-                row = fillrandom(cfg, 60_000, dist=dist, scale=SCALE,
-                                 seed=args.seed, run=run)
-                emit(f"db_bench.{dist}.io_amp.{nm}", row["io_amp"],
-                     f"levels={row['levels_filled']}")
-                if dist != "uniform":
-                    continue
-                # chain observatory off the SAME simulation (paper §3;
-                # full distributions live in db_bench's chain_report
-                # rows — see docs/benchmarks.md)
-                crow = chain_report(cfg, 60_000, scale=SCALE,
-                                    seed=args.seed, run=run)
-                emit(f"db_bench.chain.mean_width_ssts.{nm}",
-                     crow.get("mean_width_ssts", 0.0),
-                     f"eff_len={crow.get('effective_length', 0.0)}")
-    except Exception as e:  # pragma: no cover
-        print(f"# db_bench skipped: {e}")
+    from repro.bench_kv.db_bench import chain_report, fill_sim, fillrandom
+    from repro.core.policies import get_policy, resolve_names
+    from .common import SCALE, emit
+    chosen = resolve_names(args.policy)
+    for dist in ("uniform", "pareto"):
+        for nm in chosen:
+            cfg = get_policy(nm).default_config(scale=SCALE)
+            run = fill_sim(cfg, 60_000, dist, SCALE, args.seed)
+            row = fillrandom(cfg, 60_000, dist=dist, scale=SCALE,
+                             seed=args.seed, run=run)
+            emit(f"db_bench.{dist}.io_amp.{nm}", row["io_amp"],
+                 f"levels={row['levels_filled']}")
+            if dist != "uniform":
+                continue
+            # chain observatory off the SAME simulation (paper §3;
+            # full distributions live in db_bench's chain_report
+            # rows — see docs/benchmarks.md)
+            crow = chain_report(cfg, 60_000, scale=SCALE,
+                                seed=args.seed, run=run)
+            emit(f"db_bench.chain.mean_width_ssts.{nm}",
+                 crow.get("mean_width_ssts", 0.0),
+                 f"eff_len={crow.get('effective_length', 0.0)}")
     # sharded fleet: P99 vs shard count at a fixed aggregate rate, plus
     # the Zipf hot-shard interference point (full distributions live in
     # db_bench's shard_sweep rows — see docs/benchmarks.md)
-    try:
-        from repro.bench_kv.db_bench import (HOT_RATE, HOT_SHARDS,
-                                             SHARD_COUNTS, SWEEP_RATE,
-                                             shard_sweep)
-        from repro.core.policies import get_policy, resolve_names
-        from .common import SCALE, emit
-        for nm in resolve_names(args.policy):
-            for k in SHARD_COUNTS:
-                cfg = get_policy(nm).default_config(scale=SCALE) \
-                    .with_(n_shards=k)
-                row = shard_sweep(cfg, 20_000, 30_000, scale=SCALE,
-                                  rate=SWEEP_RATE, seed=args.seed)
-                emit(f"db_bench.shard_sweep.p99_get_ms.{nm}.x{k}",
-                     row["p99_get_ms"], f"p999={row['p999_get_ms']}")
+    from repro.bench_kv.db_bench import (HOT_RATE, HOT_SHARDS,
+                                         SHARD_COUNTS, SWEEP_RATE,
+                                         shard_sweep)
+    for nm in resolve_names(args.policy):
+        for k in SHARD_COUNTS:
             cfg = get_policy(nm).default_config(scale=SCALE) \
-                .with_(n_shards=HOT_SHARDS, shard_router="range")
-            row = shard_sweep(cfg, 20_000, 30_000, dist="zipf_ranked",
-                              scale=SCALE, rate=HOT_RATE, seed=args.seed)
-            emit(f"db_bench.shard_hot.p99_get_ms.{nm}.x{HOT_SHARDS}",
-                 row["p99_get_ms"],
-                 f"hot_frac={row['hot_shard_frac']};"
-                 f"stall_s={row['stall_total_s']}")
-    except Exception as e:  # pragma: no cover
-        print(f"# shard_sweep skipped: {e}")
+                .with_(n_shards=k)
+            row = shard_sweep(cfg, 20_000, 30_000, scale=SCALE,
+                              rate=SWEEP_RATE, seed=args.seed)
+            emit(f"db_bench.shard_sweep.p99_get_ms.{nm}.x{k}",
+                 row["p99_get_ms"], f"p999={row['p999_get_ms']}")
+        cfg = get_policy(nm).default_config(scale=SCALE) \
+            .with_(n_shards=HOT_SHARDS, shard_router="range")
+        row = shard_sweep(cfg, 20_000, 30_000, dist="zipf_ranked",
+                          scale=SCALE, rate=HOT_RATE, seed=args.seed)
+        emit(f"db_bench.shard_hot.p99_get_ms.{nm}.x{HOT_SHARDS}",
+             row["p99_get_ms"],
+             f"hot_frac={row['hot_shard_frac']};"
+             f"stall_s={row['stall_total_s']}")
     # batched fleet engine: the policy × shard × rate matrix as one
     # structural replay per point + batched Lindley accounting, with the
     # serial heap loop as timed baseline and parity oracle (full-size
     # matrix lives in db_bench's fleet_sweep rows — see docs/benchmarks.md)
-    try:
-        from repro.bench_kv.db_bench import (FLEET_RATES_QUICK,
-                                             fleet_sweep_bench)
-        from repro.core.policies import resolve_names
-        from .common import SCALE, emit
-        frows = fleet_sweep_bench(resolve_names(args.policy), 6_000, 8_000,
-                                  scale=SCALE, rates=FLEET_RATES_QUICK,
-                                  shard_counts=(1, 4), seed=args.seed,
-                                  workers=args.workers)
-        summary = frows[-1]
-        emit("db_bench.fleet_sweep.speedup", summary["speedup"],
-             f"runs={summary['runs']};"
-             f"fleet_wall_s={summary['fleet_wall_s']}")
-        emit("db_bench.fleet_sweep.parity_max_abs_latency_s",
-             summary["parity_max_abs_latency_s"],
-             f"stalls_equal={summary['parity_stalls_equal']}")
-        top_rate = max(r["rate_ops_s"] for r in frows[:-1])
-        for row in frows[:-1]:
-            if row["rate_ops_s"] == top_rate:
-                emit(f"db_bench.fleet_sweep.p99_get_ms."
-                     f"{row['policy']}.x{row['n_shards']}",
-                     row["p99_get_ms"], f"rate={row['rate_ops_s']}")
-    except Exception as e:  # pragma: no cover
-        print(f"# fleet_sweep skipped: {e}")
+    from repro.bench_kv.db_bench import (FLEET_RATES_QUICK,
+                                         fleet_sweep_bench)
+    frows = fleet_sweep_bench(resolve_names(args.policy), 6_000, 8_000,
+                              scale=SCALE, rates=FLEET_RATES_QUICK,
+                              shard_counts=(1, 4), seed=args.seed,
+                              workers=args.workers)
+    summary = frows[-1]
+    emit("db_bench.fleet_sweep.speedup", summary["speedup"],
+         f"runs={summary['runs']};"
+         f"fleet_wall_s={summary['fleet_wall_s']}")
+    emit("db_bench.fleet_sweep.parity_max_abs_latency_s",
+         summary["parity_max_abs_latency_s"],
+         f"stalls_equal={summary['parity_stalls_equal']}")
+    top_rate = max(r["rate_ops_s"] for r in frows[:-1])
+    for row in frows[:-1]:
+        if row["rate_ops_s"] == top_rate:
+            emit(f"db_bench.fleet_sweep.p99_get_ms."
+                 f"{row['policy']}.x{row['n_shards']}",
+                 row["p99_get_ms"], f"rate={row['rate_ops_s']}")
     # open-loop multi-tenant serving: goodput/shed/priority-tail numbers
     # at and past the saturation knee, admission off vs on (full
     # per-factor rows live in db_bench's serve_sweep output — see
     # docs/benchmarks.md)
-    try:
-        from .serving_tail import bench_serving_tail
-        bench_serving_tail(120_000 if args.full else 60_000)
-    except Exception as e:  # pragma: no cover
-        print(f"# serve_sweep skipped: {e}")
+    from .serving_tail import bench_serving_tail
+    bench_serving_tail(120_000 if args.full else 60_000)
     # distributed wire benchmark (fast, lowering only)
-    try:
-        from .compression_wire import bench_wire
-        bench_wire()
-    except Exception as e:  # pragma: no cover
-        print(f"# compression_wire skipped: {e}")
+    from .compression_wire import bench_wire
+    bench_wire()
     print(f"# total {time.time()-t0:.1f}s")
     if args.json:
         import json

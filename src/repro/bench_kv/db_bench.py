@@ -631,6 +631,8 @@ def main(argv=None):
                          "serve_sweep (1 = in-process; rows are "
                          "byte-identical at every worker count)")
     args = ap.parse_args(argv)
+    from repro.kernels.platform import enable_compile_cache
+    enable_compile_cache()
     seed = args.seed
     if args.bench == "all":
         benches = set(BENCHES)

@@ -349,7 +349,8 @@ class FleetEngine(Simulator):
             backend: str = "jnp") -> SimResult:
         """Full two-phase run.  ``backend`` picks the batched Lindley
         implementation: ``"jnp"`` (vmapped oracle — the CPU default) or
-        ``"pallas"`` (the blocked-scan TPU kernel; interpret-mode here)."""
+        ``"pallas"`` (the blocked-scan TPU kernel; interpreted off the
+        TPU)."""
         from repro.kernels.lindley_scan.ops import lindley_batch_np
         queues = self.run_prepare(op_types, keys, arrivals, scan_lens)
         deps = lindley_batch_np([q[0] for q in queues],

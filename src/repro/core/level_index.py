@@ -19,7 +19,7 @@ Rank queries are backend-switchable, mirroring ``repro.core.merge``:
 * ``numpy``  — ``np.searchsorted``; the DES hot path.
 * ``jnp``    — ``jnp.searchsorted`` under x64 (identical math on device).
 * ``pallas`` — the ``repro.kernels.overlap_scan`` fence-rank TPU kernel
-               (interpret mode on CPU); parity tests prove it drop-in.
+               (interpreted off the TPU); parity tests prove it drop-in.
 
 Every query reduces to two rank primitives over sorted int64 fences:
 ``rank_left(a, v) = #{a < v}`` and ``rank_right(a, v) = #{a <= v}``; the SSTs
@@ -88,7 +88,7 @@ def _rank(arr: np.ndarray, vals: np.ndarray, side: str,
     if backend == "jnp":
         import jax
         import jax.numpy as jnp
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             out = jnp.searchsorted(jnp.asarray(arr, jnp.int64),
                                    jnp.asarray(vals, jnp.int64), side=side)
             return np.asarray(out, np.int64)

@@ -5,9 +5,9 @@ module so the backend can be swapped:
 
 * ``numpy``  — fast CPU path used by the discrete-event simulation.
 * ``jnp``    — pure-jnp formulation (identical math to the Pallas oracle).
-* ``pallas`` — the TPU merge-path kernel (``repro.kernels.merge_path``)
-               executed in interpret mode; used by tests to prove the kernel
-               is a drop-in for the store's merge.
+* ``pallas`` — the TPU merge-path kernel (``repro.kernels.merge_path``):
+               compiled on a TPU, interpreted elsewhere (where tests prove
+               it a drop-in for the store's merge).
 
 All backends implement *latest-wins k-run merge*: runs are given newest
 first; on duplicate keys the entry from the newest run (or the highest seq)
@@ -70,7 +70,7 @@ def _merge_jnp(runs) -> tuple[np.ndarray, np.ndarray]:
     import jax
     import jax.numpy as jnp
 
-    with jax.experimental.enable_x64():   # keys are true int64
+    with jax.enable_x64(True):             # keys are true int64
         keys = jnp.concatenate([jnp.asarray(r[0], jnp.int64) for r in runs])
         seqs = jnp.concatenate([jnp.asarray(r[1], jnp.int64) for r in runs])
         order = jnp.lexsort((seqs, keys))
@@ -78,8 +78,30 @@ def _merge_jnp(runs) -> tuple[np.ndarray, np.ndarray]:
     return _dedup_latest(k, s)
 
 
+def _disjoint_groups(runs) -> list[tuple[np.ndarray, np.ndarray]]:
+    """Fold consecutive runs whose key ranges are pairwise disjoint (the
+    SSTs of one sorted level) into one run each, concatenated in key
+    order.  A key occurs at most once per group and groups keep the
+    runs' order, so merging groups in order sees duplicates in the same
+    age order as merging the runs one by one."""
+    groups: list[list[tuple[np.ndarray, np.ndarray]]] = []
+    for run in runs:
+        lo, hi = run[0][0], run[0][-1]
+        if groups and all(hi < k[0] or lo > k[-1] for k, _ in groups[-1]):
+            groups[-1].append(run)
+        else:
+            groups.append([run])
+    out = []
+    for g in groups:
+        g.sort(key=lambda r: r[0][0])
+        out.append((np.concatenate([r[0] for r in g]),
+                    np.concatenate([r[1] for r in g])))
+    return out
+
+
 def _merge_pallas(runs) -> tuple[np.ndarray, np.ndarray]:
-    """Reduce pairwise with the TPU merge-path kernel (interpret mode).
+    """Reduce pairwise with the TPU merge-path kernel, one launch per
+    group of disjoint runs.
 
     The kernel performs a *stable* merge (ties: left run first), so feeding
     runs oldest-first keeps duplicate keys seq-ascending, which is what
@@ -88,7 +110,7 @@ def _merge_pallas(runs) -> tuple[np.ndarray, np.ndarray]:
     """
     from repro.kernels.merge_path import ops as mp_ops
 
-    ordered = runs[::-1]  # oldest first
+    ordered = _disjoint_groups(runs[::-1])  # oldest first
     acc_k, acc_s = ordered[0]
     for k, s in ordered[1:]:
         acc_k, acc_s = mp_ops.merge_two_runs_np(acc_k, acc_s, k, s)

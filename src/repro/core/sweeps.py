@@ -36,6 +36,12 @@ pre-warmed entries are free); their own ``put``\\ s stay in the child,
 so cross-point reuse inside one ``sweep_execute`` call only happens
 when two points land on the same worker — the in-process ``workers=1``
 path sees every hit.
+
+The pool is for the numpy tier only.  A chip belongs to one process, and
+a child forked from a parent that has touched JAX fails or hangs when it
+needs the device, so ``workers > 1`` with a device tier selected (a
+``jnp``/``pallas`` Lindley backend, merge backend or manifest backend)
+raises instead of forking.
 """
 
 from __future__ import annotations
@@ -49,6 +55,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from . import level_index, merge
 from .fleet import FleetEngine, SweepPoint
 from .sim import SimResult, Simulator
 from .uids import UidNamespace
@@ -285,6 +292,23 @@ def _serial_task(task: tuple[int, int]) -> SimResult:
     return sim.run(p.op_types, p.keys, p.grid[ai], p.scan_lens)
 
 
+def _check_fork_safe(workers: int, backend: str = "numpy",
+                    points: list[SweepPoint] = ()) -> None:
+    """Raise if ``workers > 1`` would fork workers while a device tier is
+    selected: the chip belongs to the one process that touched it."""
+    if workers <= 1:
+        return
+    tiers = {"lindley": backend, "merge": merge.get_backend(),
+             "index": level_index.get_backend()}
+    tiers.update({f"index[{p.label}]": p.cfg.index_backend
+                  for p in points if p.cfg.index_backend is not None})
+    device = {k: v for k, v in tiers.items() if v != "numpy"}
+    if device:
+        raise ValueError(f"workers={workers} would fork processes with a "
+                         f"device tier selected ({device}); the device "
+                         "tiers run in one process: use workers=1")
+
+
 def _fork_map(fn, tasks: list, workers: int) -> list:
     ctx = multiprocessing.get_context("fork")
     with ctx.Pool(processes=min(workers, len(tasks))) as pool:
@@ -298,6 +322,7 @@ def parallel_map(fn, items, *, workers: int = 1) -> list:
     ``workers > 1`` (standard ``multiprocessing`` contract); ``workers
     <= 1`` is a plain in-process loop with no pool, no pickling.
     """
+    _check_fork_safe(workers)
     items = list(items)
     if workers <= 1 or len(items) <= 1:
         return [fn(x) for x in items]
@@ -320,6 +345,7 @@ def sweep_execute(points: list[SweepPoint], *, workers: int = 1,
     :func:`repro.core.fleet.fleet_sweep`, rows byte-identical to it.
     """
     global _FORK_STATE
+    _check_fork_safe(workers, backend, points)
     t0 = time.perf_counter()
     if workers <= 1 or len(points) <= 1:
         pairs = [run_point(p, backend=backend, cache=cache) for p in points]
@@ -345,6 +371,7 @@ def serial_sweep_parallel(points: list[SweepPoint], *,
     Byte-identical results to ``serial_sweep`` — the namespace ≡ reset
     equivalence — in the same per-point grouping."""
     global _FORK_STATE
+    _check_fork_safe(workers, points=points)
     tasks = [(pi, ai) for pi, p in enumerate(points)
              for ai in range(len(p.grid))]
     _FORK_STATE = (list(points),)

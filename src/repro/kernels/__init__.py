@@ -11,6 +11,6 @@
 #   ssd_scan        — Mamba2 SSD chunked scan
 #
 # Each subpackage ships kernel.py (pl.pallas_call + BlockSpec), ops.py
-# (jit'd wrapper) and ref.py (pure-jnp oracle).  Kernels are validated in
-# interpret=True mode on CPU; TPU is the target.  Import lazily — these pull
-# in jax.
+# (jit'd wrapper) and ref.py (pure-jnp oracle).  platform.py decides the
+# mode: compiled on a TPU, interpreted elsewhere (the CPU tests).  Import
+# lazily — these pull in jax.

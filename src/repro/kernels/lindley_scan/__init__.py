@@ -1,4 +1,4 @@
 from . import ops, ref
-from .kernel import TILE, lindley_scan_call
+from .kernel import BLOCK, departure_tolerance, lindley_scan_call
 
-__all__ = ["TILE", "lindley_scan_call", "ops", "ref"]
+__all__ = ["BLOCK", "departure_tolerance", "lindley_scan_call", "ops", "ref"]

@@ -1,10 +1,12 @@
-"""Numpy entry points for lindley_scan: x64 scope, padding, ragged batch.
+"""Numpy entry points for lindley_scan: padding, ragged batch, the
+double-f32 split for the kernel and the x64 scope for the jnp oracle.
 
 The DES hands over ragged per-queue (service, arrivals) arrays — one row
 per shard, or per (policy, config, shard) point of a whole sweep matrix.
-``lindley_batch_np`` pads them into ONE [B, N] program (pallas blocked
-scan, or the vmapped jnp oracle) and slices the departures back out; the
-fleet engine's final latency accounting is exactly one such call.
+``lindley_batch_np`` pads them into ONE [B, N] program per length bucket
+(pallas blocked scan, or the vmapped jnp oracle) and slices the
+departures back out; the fleet engine's final latency accounting is
+exactly one such call.
 """
 
 from __future__ import annotations
@@ -13,7 +15,8 @@ from collections import OrderedDict
 
 import numpy as np
 
-from .kernel import TILE, lindley_scan_call
+from ..platform import bucket, interpret_mode
+from .kernel import BLOCK, LANES, NEG, lindley_scan_call
 
 _NEG_INF = float("-inf")
 
@@ -44,10 +47,7 @@ def _pad_plan(lens: tuple[int, ...]) -> list[tuple]:
     for i, ln in enumerate(lens):
         if ln == 0:
             continue
-        n_pad = TILE
-        while n_pad < ln:
-            n_pad *= 2
-        buckets.setdefault(n_pad, []).append(i)
+        buckets.setdefault(bucket(ln, BLOCK), []).append(i)
     plan = []
     for n_pad, idxs in sorted(buckets.items()):
         S = np.zeros((len(idxs), n_pad), np.float64)
@@ -71,20 +71,21 @@ def clear_pad_plans() -> None:
 
 def lindley_batch_np(services: list[np.ndarray], arrivals: list[np.ndarray],
                      d0: list[float] | None = None,
-                     backend: str = "pallas",
-                     interpret: bool = True) -> list[np.ndarray]:
+                     backend: str = "pallas") -> list[np.ndarray]:
     """Departure times for a ragged batch of FIFO queues.
 
     ``services[i]``/``arrivals[i]`` are queue i's per-op service times and
     arrival times (1-D, equal length, possibly empty); ``d0[i]`` the
     carried-in departure clock (default -inf: fresh queue).  Returns the
     per-queue departure arrays.  ``backend``: "pallas" (blocked-scan
-    kernel, interpret mode on CPU), "jnp" (vmapped oracle), or "numpy"
+    kernel in double-f32, interpreted off the TPU; within
+    ``departure_tolerance`` of the numpy recursion), "jnp" (vmapped float64
+    oracle, CPU only), or "numpy"
     (:func:`lindley_numpy` per queue — no padding, no device: XLA's CPU
     lowering serializes cumulative scans at ~20x numpy's throughput and
     the padded batch costs ~2x extra memory traffic, so this is the
-    CPU-tier choice for large sweep matrices; all three are asserted
-    equal in the kernel tests).
+    CPU-tier choice for large sweep matrices; the kernel tests assert
+    that all three agree).
 
     Very ragged batches (a sweep mixing 1-shard and 16-shard queues) are
     padded in power-of-two length *buckets* rather than to the single
@@ -125,34 +126,50 @@ def lindley_batch_np(services: list[np.ndarray], arrivals: list[np.ndarray],
             np.maximum.accumulate(gg, out=gg)
             outs.append(cc + gg)
         return outs
-    # bucket i by padded length: TILE * 2^ceil(log2(len/TILE)) — the
+    # bucket i by padded length: BLOCK * 2^ceil(log2(len/BLOCK)) — the
     # plan (bucket map + padded buffers) is cached across calls
     out: list[np.ndarray | None] = [np.empty(0, np.float64)] * b
-    import jax
-    with jax.experimental.enable_x64():
-        for n_pad, idxs, S, A in _pad_plan(tuple(lens)):
-            for row, i in enumerate(idxs):
-                S[row, :lens[i]] = services[i]
-                A[row, :lens[i]] = arrivals[i]
-            D0 = np.asarray([d0[i] for i in idxs], np.float64)
-            if backend == "pallas":
-                dep = lindley_scan_call(S, A, D0, interpret=interpret)
-            else:
-                from .ref import lindley_ref_batch
-                dep = lindley_ref_batch(S, A, D0)
-            dep = np.asarray(dep, np.float64)
-            for row, i in enumerate(idxs):
-                out[i] = dep[row, :lens[i]]
+    for n_pad, idxs, S, A in _pad_plan(tuple(lens)):
+        for row, i in enumerate(idxs):
+            S[row, :lens[i]] = services[i]
+            A[row, :lens[i]] = arrivals[i]
+        D0 = np.asarray([d0[i] for i in idxs], np.float64)
+        dep = _pallas(S, A, D0) if backend == "pallas" else _jnp(S, A, D0)
+        for row, i in enumerate(idxs):
+            out[i] = dep[row, :lens[i]]
     return out
 
 
+def _pairs(x: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """float64 -> double-f32 (hi, lo) with hi + lo == x to ~2^-48."""
+    x = np.maximum(x, NEG)          # -inf padding / fresh clock -> NEG
+    hi = x.astype(np.float32)
+    return hi, (x - hi).astype(np.float32)
+
+
+def _pallas(S: np.ndarray, A: np.ndarray, D0: np.ndarray) -> np.ndarray:
+    b, n = S.shape
+    planes = [p.reshape(b, n // LANES, LANES)
+              for p in (*_pairs(S), *_pairs(A))]
+    d0 = np.stack(_pairs(D0), axis=1).reshape(-1)
+    hi, lo = lindley_scan_call(d0, *planes, interpret=interpret_mode())
+    return (np.asarray(hi, np.float64) + np.asarray(lo, np.float64)
+            ).reshape(b, n)
+
+
+def _jnp(S: np.ndarray, A: np.ndarray, D0: np.ndarray) -> np.ndarray:
+    import jax
+    from .ref import lindley_ref_batch
+    with jax.enable_x64(True):
+        return np.asarray(lindley_ref_batch(S, A, D0), np.float64)
+
+
 def lindley_np(service: np.ndarray, arrivals: np.ndarray,
-               d0: float = _NEG_INF, backend: str = "pallas",
-               interpret: bool = True) -> np.ndarray:
+               d0: float = _NEG_INF, backend: str = "pallas") -> np.ndarray:
     """Single-queue convenience wrapper over :func:`lindley_batch_np`."""
     return lindley_batch_np([np.asarray(service, np.float64)],
                             [np.asarray(arrivals, np.float64)],
-                            [d0], backend=backend, interpret=interpret)[0]
+                            [d0], backend=backend)[0]
 
 
 def lindley_numpy(service: np.ndarray, arrivals: np.ndarray,

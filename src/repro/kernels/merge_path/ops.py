@@ -1,15 +1,13 @@
-"""jit'd wrapper around the merge-path kernel: int64 <-> (hi, lo) planes,
-sentinel padding, and the numpy convenience entry used by the LSM core's
-``pallas`` merge backend."""
+"""Host wrapper around the merge-path kernel: int64 <-> (hi, lo) planes,
+sentinel padding to power-of-two buckets, and the numpy entry used by the
+LSM core's ``pallas`` merge backend."""
 
 from __future__ import annotations
 
-import jax.numpy as jnp
 import numpy as np
 
-from .kernel import HI_SENTINEL, LO_SENTINEL, TILE, merge_path_call
-
-_BIAS = np.int64(0x8000_0000)
+from ..platform import bucket, interpret_mode
+from .kernel import BLOCK, PLANES, SENTINEL, SUB, TILE, merge_path_call
 
 
 def split_planes(keys: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
@@ -33,39 +31,32 @@ def join_planes(hi: np.ndarray, lo: np.ndarray) -> np.ndarray:
     return (hi << 32) | raw
 
 
-def _pad_run(hi: np.ndarray, lo: np.ndarray, sq: np.ndarray
-             ) -> tuple[np.ndarray, np.ndarray, np.ndarray, int]:
-    n = hi.shape[0]
-    n_pad = max(TILE, ((n + TILE - 1) // TILE) * TILE)
-    total = n_pad + TILE  # one extra sentinel tile for window loads
-    def pad(x, fill):
-        out = np.full(total, fill, np.int32)
-        out[:n] = x
-        return out
-    return (pad(hi, HI_SENTINEL), pad(lo, LO_SENTINEL),
-            pad(sq, 0), n_pad)
+def _pack_run(keys: np.ndarray, seqs: np.ndarray) -> np.ndarray:
+    """A run as the kernel's ``[3, G, 8, 128]`` planes: bucket-padded with
+    sentinels, plus one whole sentinel block for the window loads."""
+    n = keys.shape[0]
+    total = bucket(n, BLOCK) + BLOCK
+    buf = np.empty((PLANES, total), np.int32)
+    buf[:2, n:] = SENTINEL
+    buf[2, n:] = 0
+    buf[0, :n], buf[1, :n] = split_planes(keys)
+    buf[2, :n] = seqs
+    return buf.reshape(PLANES, total // BLOCK, SUB, TILE)
 
 
 def merge_two_runs_np(a_keys: np.ndarray, a_seqs: np.ndarray,
-                      b_keys: np.ndarray, b_seqs: np.ndarray,
-                      interpret: bool = True
+                      b_keys: np.ndarray, b_seqs: np.ndarray
                       ) -> tuple[np.ndarray, np.ndarray]:
-    """Stable merge of two sorted int64 runs via the TPU kernel
-    (interpret mode on CPU).  Seqnos must fit int32."""
+    """Stable merge of two sorted int64 runs via the TPU kernel (ties: A
+    first; interpreted off the TPU).  Seqnos must fit int32."""
     n, m = int(a_keys.shape[0]), int(b_keys.shape[0])
     if n == 0:
         return np.asarray(b_keys, np.int64), np.asarray(b_seqs, np.int64)
     if m == 0:
         return np.asarray(a_keys, np.int64), np.asarray(a_seqs, np.int64)
-    assert np.all(np.abs(a_seqs) < 2**31) and np.all(np.abs(b_seqs) < 2**31)
-    a_hi, a_lo = split_planes(a_keys)
-    b_hi, b_lo = split_planes(b_keys)
-    a_hi, a_lo, a_sq, n_a = _pad_run(a_hi, a_lo, np.asarray(a_seqs, np.int32))
-    b_hi, b_lo, b_sq, n_b = _pad_run(b_hi, b_lo, np.asarray(b_seqs, np.int32))
-    o_hi, o_lo, o_sq = merge_path_call(
-        jnp.asarray(a_hi), jnp.asarray(a_lo), jnp.asarray(a_sq),
-        jnp.asarray(b_hi), jnp.asarray(b_lo), jnp.asarray(b_sq),
-        n_a=n_a, n_b=n_b, interpret=interpret)
-    keys = join_planes(np.asarray(o_hi), np.asarray(o_lo))[:n + m]
-    seqs = np.asarray(o_sq, np.int64)[:n + m]
-    return keys, seqs
+    if not (np.all(np.abs(a_seqs) < 2**31) and np.all(np.abs(b_seqs) < 2**31)):
+        raise ValueError("merge_path carries seqnos as int32")
+    out = merge_path_call(_pack_run(a_keys, a_seqs), _pack_run(b_keys, b_seqs),
+                          interpret=interpret_mode())
+    planes = np.asarray(out).transpose(1, 0, 2).reshape(PLANES, -1)[:, :n + m]
+    return join_planes(planes[0], planes[1]), planes[2].astype(np.int64)

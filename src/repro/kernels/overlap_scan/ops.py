@@ -1,44 +1,40 @@
-"""jit'd wrapper for overlap_scan: plane splitting, padding, numpy entry."""
+"""Host wrapper for overlap_scan: plane splitting, bucket padding, numpy
+entry."""
 
 from __future__ import annotations
 
-import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.merge_path.ops import join_planes, split_planes  # noqa: F401
-from .kernel import TILE, fence_rank_call
+from ..merge_path.ops import split_planes
+from ..platform import bucket, interpret_mode
+from .kernel import BLOCK, TILE, fence_rank_call
 
 _HI_SENT = np.int32(np.iinfo(np.int32).max)
 
 
-def _pad_planes(hi: np.ndarray, lo: np.ndarray, fill_hi, fill_lo
-                ) -> tuple[np.ndarray, np.ndarray, int]:
-    n = hi.shape[0]
-    n_pad = max(TILE, ((n + TILE - 1) // TILE) * TILE)
-    H = np.full(n_pad, fill_hi, np.int32)
-    L = np.full(n_pad, fill_lo, np.int32)
-    H[:n] = hi
-    L[:n] = lo
-    return H, L, n_pad
+def _pad_planes(vals: np.ndarray, n_pad: int) -> tuple[np.ndarray, ...]:
+    hi, lo = split_planes(vals)
+    H = np.full(n_pad, _HI_SENT, np.int32)
+    L = np.full(n_pad, _HI_SENT, np.int32)
+    H[:hi.shape[0]] = hi
+    L[:lo.shape[0]] = lo
+    return H, L
 
 
-def fence_rank_np(fences: np.ndarray, keys: np.ndarray,
-                  interpret: bool = True) -> np.ndarray:
+def fence_rank_np(fences: np.ndarray, keys: np.ndarray) -> np.ndarray:
     """#fences <= key, per key (== np.searchsorted(fences, keys, 'right'))."""
+    keys = np.asarray(keys, np.int64)
     if fences.shape[0] == 0:
         return np.zeros(keys.shape[0], np.int32)
-    f_hi, f_lo = split_planes(np.asarray(fences, np.int64))
-    k_hi, k_lo = split_planes(np.asarray(keys, np.int64))
-    f_hi, f_lo, n_f = _pad_planes(f_hi, f_lo, _HI_SENT, _HI_SENT)
-    k_hi, k_lo, _ = _pad_planes(k_hi, k_lo, _HI_SENT, _HI_SENT)
-    out = fence_rank_call(jnp.asarray(f_hi), jnp.asarray(f_lo),
-                          jnp.asarray(k_hi), jnp.asarray(k_lo),
-                          n_fences=n_f, interpret=interpret)
-    return np.asarray(out)[:keys.shape[0]]
+    f_hi, f_lo = _pad_planes(fences, bucket(fences.shape[0], TILE))
+    n_pad = bucket(keys.shape[0], BLOCK)
+    k_hi, k_lo = (p.reshape(n_pad // TILE, TILE)
+                  for p in _pad_planes(keys, n_pad))
+    out = fence_rank_call(f_hi, f_lo, k_hi, k_lo, interpret=interpret_mode())
+    return np.asarray(out).reshape(-1)[:keys.shape[0]]
 
 
-def fence_rank_strict_np(fences: np.ndarray, keys: np.ndarray,
-                         interpret: bool = True) -> np.ndarray:
+def fence_rank_strict_np(fences: np.ndarray, keys: np.ndarray) -> np.ndarray:
     """#fences < key, per key (== np.searchsorted(fences, keys, 'left')).
 
     Integer keys only: the strict rank is the inclusive rank of ``key - 1``.
@@ -46,16 +42,5 @@ def fence_rank_strict_np(fences: np.ndarray, keys: np.ndarray,
     ``pallas`` backend (start-of-overlap = strict rank of ``lo`` over the
     level's ``largest`` fences).
     """
-    return fence_rank_np(fences, np.asarray(keys, np.int64) - 1, interpret)
+    return fence_rank_np(fences, np.asarray(keys, np.int64) - 1)
 
-
-def overlap_counts_np(fence_lo: np.ndarray, fence_hi: np.ndarray,
-                      key_lo: np.ndarray, key_hi: np.ndarray,
-                      interpret: bool = True) -> np.ndarray:
-    """Vectorized §4.2 overlap: #L2 SSTs intersecting each [key_lo, key_hi]
-    candidate vSST range = rank_right(fence_lo, key_hi) -
-    rank_right_strict(fence_hi, key_lo)."""
-    last = fence_rank_np(fence_lo, key_hi, interpret)
-    # rank of fence_hi STRICTLY below key_lo == #fences <= key_lo - 1
-    first = fence_rank_np(fence_hi, key_lo - 1, interpret)
-    return np.maximum(0, last - first)

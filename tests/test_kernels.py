@@ -1,5 +1,6 @@
-"""Per-kernel allclose vs pure-jnp oracles, shape/dtype sweeps
-(interpret=True — kernel bodies execute on CPU; TPU is the target)."""
+"""Per-kernel allclose vs pure-jnp oracles, shape/dtype sweeps (kernel
+bodies run in the Pallas interpreter on the CPU; TPU is the target —
+tests/test_tpu_compile.py compiles the store's three for it)."""
 
 import numpy as np
 import pytest
@@ -37,6 +38,49 @@ def test_merge_path_planes_roundtrip():
     # order preservation under (hi, lo) lexicographic compare
     order = np.lexsort((lo.astype(np.int64), hi.astype(np.int64)))
     assert np.array_equal(keys[order], np.sort(keys))
+
+
+def test_merge_path_compiles_once_per_bucket():
+    """Run lengths pad to power-of-two buckets: merges of many lengths
+    inside one bucket pair share one compiled kernel shape."""
+    from repro.kernels.merge_path import merge_path_call, ops
+    rng = np.random.default_rng(11)
+    before = merge_path_call._cache_size()
+    for n, m in [(1100, 1500), (1300, 2000), (2047, 1025), (1999, 1800)]:
+        a = np.sort(rng.choice(2**40, n, replace=False)).astype(np.int64)
+        b = np.sort(rng.choice(2**40, m, replace=False)).astype(np.int64)
+        k, _ = ops.merge_two_runs_np(a, np.arange(n), b, np.arange(m))
+        assert np.array_equal(k, np.sort(np.concatenate([a, b]),
+                                          kind="stable"))
+    assert merge_path_call._cache_size() - before <= 1
+    from repro.kernels.platform import bucket
+    assert bucket(1025, 1024) == 2048 == bucket(2048, 1024)
+
+
+def test_pallas_merge_folds_disjoint_runs():
+    """A compaction's inputs — disjoint SSTs of two sorted levels plus an
+    overlapping L0 run — merge on the kernel exactly like numpy, with
+    latest-wins dedup across the folded groups."""
+    from repro.core import merge as merge_backend
+    rng = np.random.default_rng(12)
+    keys = np.sort(rng.choice(2**40, 6000, replace=False)).astype(np.int64)
+    lower = [keys[i:i + 1000] for i in range(0, 6000, 1000)]   # "L2" SSTs
+    upper = [keys[500:2500:2], keys[3000:5000:3]]              # "L1" SSTs
+    l0 = np.sort(rng.choice(keys, 700, replace=False))
+    seq = iter(range(10**6))
+    def run(k):
+        return k, np.fromiter((next(seq) for _ in k), np.int64, k.shape[0])
+    runs = [run(k) for k in lower] + [run(k) for k in upper] + [run(l0)]
+    runs = runs[::-1]                                          # newest first
+    groups = merge_backend._disjoint_groups(runs[::-1])
+    assert len(groups) == 3
+    want = merge_backend._merge_numpy(runs)
+    merge_backend.set_backend("pallas")
+    try:
+        got = merge_backend.merge_runs(runs)
+    finally:
+        merge_backend.set_backend("numpy")
+    assert np.array_equal(got[0], want[0]) and np.array_equal(got[1], want[1])
 
 
 # ------------------------------------------------------------ overlap_scan
@@ -77,6 +121,31 @@ def test_lindley_scan(n, rho, d0):
     # departures are monotone and never precede arrival + service
     assert np.all(np.diff(want) >= -1e-15)
     assert np.all(want >= arrivals + service - 1e-9)
+
+
+@pytest.mark.parametrize("kind", ["constant", "exponential"])
+def test_lindley_scan_within_stated_tolerance(kind):
+    """A long saturated queue at DES-scale absolute times: the double-f32
+    kernel stays within ``departure_tolerance`` of the numpy recursion,
+    and its own error against an extended-precision recursion stays
+    inside the kernel's share of that bound."""
+    from repro.kernels.lindley_scan import departure_tolerance, ops
+    from repro.kernels.lindley_scan.kernel import BLOCK
+    n = 40_000
+    rng = np.random.default_rng(3)
+    service = np.full(n, 1.7e-5) if kind == "constant" \
+        else rng.exponential(1.7e-5, n)
+    arrivals = 90.0 + np.arange(n) * 1e-6     # flood: one busy period
+    want = ops.lindley_numpy(service, arrivals)
+    got = ops.lindley_np(service, arrivals, backend="pallas")
+    scale = float(np.max(np.abs(want)))
+    assert np.max(np.abs(got - want)) <= departure_tolerance(n, scale)
+    s = np.cumsum(service.astype(np.longdouble))
+    base = arrivals.astype(np.longdouble)
+    base[1:] -= s[:-1]
+    exact = s + np.maximum.accumulate(base)
+    kernel_share = (n / BLOCK + 16) * 2.0**-46 * scale
+    assert float(np.max(np.abs(got - exact))) <= kernel_share
 
 
 def test_lindley_scan_batched_ragged():
@@ -170,3 +239,30 @@ def test_ssd_scan(b, L, h, g, p, n, ck, dtype):
     tol = 6e-2 if dtype == "bfloat16" else 2e-4
     assert float(jnp.max(jnp.abs(got.astype(jnp.float32)
                                  - ref.astype(jnp.float32)))) < tol
+
+
+# ---------------------------------------------------------------- platform
+def test_interpret_mode_follows_the_platform():
+    from repro.kernels.platform import interpret_mode
+    assert interpret_mode() is (jax.default_backend() != "tpu")
+
+
+def test_compile_cache_directory(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins when set; otherwise the fixed
+    checkout-root directory, never a temp or per-process name."""
+    from repro.kernels import platform
+    before = (jax.config.jax_compilation_cache_dir,
+              jax.config.jax_persistent_cache_min_compile_time_secs)
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert platform.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before[0]
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = platform.enable_compile_cache()
+        assert path == str(platform.CACHE_DIR)
+        assert platform.CACHE_DIR.name == ".jax_cache"
+        assert (platform.CACHE_DIR.parent / "pyproject.toml").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          before[1])

@@ -200,10 +200,12 @@ def test_merge_backends_agree():
     runs = [(b, np.arange(500, 500 + b.size)), (a, np.arange(a.size))]
     merge_backend.set_backend("numpy")
     k1, s1 = merge_backend.merge_runs(runs)
-    merge_backend.set_backend("jnp")
-    k2, s2 = merge_backend.merge_runs(runs)
-    merge_backend.set_backend("pallas")
-    k3, s3 = merge_backend.merge_runs(runs)
-    merge_backend.set_backend("numpy")
+    try:
+        merge_backend.set_backend("jnp")
+        k2, s2 = merge_backend.merge_runs(runs)
+        merge_backend.set_backend("pallas")
+        k3, s3 = merge_backend.merge_runs(runs)
+    finally:
+        merge_backend.set_backend("numpy")
     assert np.array_equal(k1, k2) and np.array_equal(s1, s2)
     assert np.array_equal(k1, k3) and np.array_equal(s1, s3)

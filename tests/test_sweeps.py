@@ -189,6 +189,39 @@ def test_cache_lru_eviction():
     assert keys[1] not in cache
 
 
+# ------------------------------------------------ one process per chip
+
+@pytest.mark.parametrize("tier", ["lindley", "merge", "index", "cfg_index"])
+def test_fork_pool_refuses_device_tiers(tier):
+    """workers > 1 with a device tier selected raises before any fork:
+    the chip belongs to one process, and a forked child of a parent
+    that touched JAX fails or hangs on it."""
+    from repro.core import level_index, merge
+    from repro.core.sweeps import parallel_map
+    points = _points(POLICIES[:1], n=200)
+    backend = "jnp" if tier == "lindley" else "numpy"
+    if tier == "cfg_index":
+        points[0].cfg = points[0].cfg.with_(index_backend="pallas")
+    switch = {"merge": merge, "index": level_index}.get(tier)
+    try:
+        if switch is not None:
+            switch.set_backend("pallas")
+        with pytest.raises(ValueError, match="device tier"):
+            sweep_execute(points, workers=2, backend=backend)
+        if tier != "lindley":
+            with pytest.raises(ValueError, match="device tier"):
+                serial_sweep_parallel(points, workers=2)
+        if switch is not None:
+            with pytest.raises(ValueError, match="device tier"):
+                parallel_map(abs, [1, -2], workers=2)
+    finally:
+        if switch is not None:
+            switch.set_backend("numpy")
+    # one process is always allowed
+    r1, _ = sweep_execute(points, workers=1, backend="numpy")
+    assert len(r1) == 1
+
+
 # ----------------------------------------------------- pad-plan caching
 
 def test_lindley_pad_plan_reused_across_calls():
