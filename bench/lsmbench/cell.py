@@ -1,0 +1,337 @@
+"""One cell: its set-up, its passes, the measured window, and the check.
+
+A *pass* produces one ``SimResult`` through the store's phases on the
+device tier, from input that no earlier pass of the process has seen: a
+fresh engine replays the seed's stream with its keys mapped through the
+pass's own bijection of the key space (see ``streams.Stream.mapped``),
+then the temporal pass, Lindley and finalize.
+
+The window rule: passes start while the elapsed time is under the
+window's length; the window ends when the last pass that started ends.
+The harness keeps a few passes for the check, drawn from the seed, and
+with them a seeded sample of each tapped kernel's calls in those passes.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import itertools
+import time
+from dataclasses import dataclass, field
+
+import numpy as np
+
+from . import program, reference
+from .streams import Stream, Traffic, key_map, rng
+from .trace import WINDOW
+
+
+@dataclass
+class PassRecord:
+    index: int
+    ops: int
+    start: float
+    wall_s: float
+    phases: dict[str, float]
+    compaction_keys: int      # keys read + written by the pass's compactions
+
+
+@dataclass
+class Kept:
+    """A pass kept for the check: its store, what its temporal pass and
+    Lindley produced, and the map that made its stream from the seed's."""
+
+    index: int
+    key_map: tuple[int, int]
+    engine: object
+    queues: list
+    latency: np.ndarray
+    get_reads: np.ndarray
+    jobs: dict
+    stalls: tuple[np.ndarray, np.ndarray]
+    shard_ids: np.ndarray | None
+    calls: dict[str, list] = field(default_factory=dict)
+
+
+class Reservoir:
+    """The passes kept for the check: ``k`` of the window's first
+    ``among`` passes, drawn from the seed.  A kept pass's queues and
+    latencies are copied into buffers made in set-up, so that keeping
+    them leaves the store's own allocations as they would be."""
+
+    def __init__(self, k: int, among: int, seed: int):
+        self.chosen = sorted(rng(seed, 404).choice(
+            np.arange(1, among + 1), size=min(k, among),
+            replace=False).tolist())
+        self.kept: list[Kept | None] = [None] * len(self.chosen)
+
+    def slot(self, i: int) -> int | None:
+        """The slot pass ``i`` fills, or None."""
+        return self.chosen.index(i) if i in self.chosen else None
+
+
+class Cell:
+    def __init__(self, name: str, conf: dict, traffic: Traffic,
+                 kernels: dict[str, program.Kernel], seed: int,
+                 annotate: bool = False):
+        self.name = name
+        self.conf = conf
+        self.traffic = traffic
+        self.kernels = kernels
+        self.seed = seed
+        self.annotate = annotate
+        self.cfg = program.build_config(conf["store"])
+        self.device = program.build_device(conf["device"])
+        self.record_count = int(conf["record_count"])
+        self.reservoir = Reservoir(traffic.keep_passes, traffic.keep_among,
+                                   seed)
+        self.taps: dict[str, program.Tap] = {}
+        for k in kernels.values():
+            if k.reference or k.warm:
+                tap = program.Tap(k, traffic.call_sample,
+                                  rng(seed, 505, len(self.taps)))
+                if tap.install():
+                    self.taps[k.name] = tap
+        self.base: Stream | None = None
+        self.slots: list | None = None
+        self.warm: list[PassRecord] = []
+        self.passes: list[PassRecord] = []
+        self.failed = 0
+        self.error = ""
+
+    # ----------------------------------------------------------- phases
+    def _span(self, name: str):
+        if not self.annotate:
+            return contextlib.nullcontext()
+        import jax
+        return jax.profiler.TraceAnnotation(name)
+
+    def _arm(self, on: bool) -> None:
+        for tap in self.taps.values():
+            tap.armed = on
+            if on:
+                tap.calls = []
+
+    def _calls(self) -> dict[str, list]:
+        return {n: t.calls for n, t in self.taps.items()}
+
+    def setup(self) -> None:
+        """The seed's stream, the warm-up passes (each over a map of its
+        own), then the size ladder, so that every program the window uses
+        is compiled or loaded before it starts."""
+        self.base = self.traffic.base_stream(self.record_count, self.seed)
+        for tap in self.taps.values():
+            tap.sizes = set() if tap.kernel.warm else None
+        self.warm = [self.run_pass(-w, keep=False)
+                     for w in range(self.traffic.warm_passes)]
+        t = time.perf_counter()
+        self.ladder_calls = self._warm_ladder()
+        self.ladder_s = time.perf_counter() - t
+
+    def _warm_ladder(self) -> int:
+        """Calls each kernel whose sizes vary at every combination of
+        powers of two from half the least to twice the most that the
+        warm-up passes used on each axis.  A pass's merges and fence ranks
+        take sizes that depend on its key map (a later pass may reach a
+        size class no warm-up pass did); the ladder compiles their
+        neighbours too.  Returns the number of calls."""
+        n = 0
+        pool = np.sort(rng(self.seed, 707).integers(
+            0, 1 << 48, 1 << 22, dtype=np.int64))
+        for tap in self.taps.values():
+            seen, tap.sizes = tap.sizes, None
+            if not seen:
+                continue
+            axes = []
+            for sizes in zip(*seen):
+                lo = _pow2(min(sizes)) // 2
+                hi = min(_pow2(max(sizes)) * 2, pool.shape[0])
+                axes.append([1 << e for e in range(max(lo, 1).bit_length() - 1,
+                                                   hi.bit_length())])
+            for combo in itertools.product(*axes):
+                tap.fn(*tap.kernel.make_args(combo, pool))
+                n += 1
+        return n
+
+    def run_pass(self, i: int, keep: bool = True) -> PassRecord:
+        slot = self.reservoir.slot(i) if keep else None
+        t0 = time.perf_counter()
+        phases = {}
+        kmap = key_map(self.seed, i)
+        stream = self.base.mapped(kmap)
+        self._arm(slot is not None)
+        with self._span("structural"):
+            eng = program.new_engine(self.cfg, self.device)
+            eng.prepare_structural(stream.op_types, stream.keys)
+        phases["structural"] = time.perf_counter() - t0
+        self._arm(False)
+        t = time.perf_counter()
+        with self._span("temporal"):
+            pending = eng.temporal_pass(stream.arrivals)
+        phases["temporal"] = time.perf_counter() - t
+        t = time.perf_counter()
+        with self._span("lindley"):
+            deps = program.lindley([q[0] for q in pending.queues],
+                                   [q[1] for q in pending.queues])
+        phases["lindley"] = time.perf_counter() - t
+        t = time.perf_counter()
+        with self._span("finalize"):
+            res = eng.finalize(deps, pending=pending)
+        t1 = time.perf_counter()
+        phases["finalize"] = t1 - t
+        read, written = program.compaction_bytes(pending.job_log,
+                                                 self.cfg.kv_size)
+        rec = PassRecord(i, stream.n, t0, t1 - t0, phases, read + written)
+        if self.slots is None:
+            self._make_slots(pending.queues, stream.n)
+        if slot is not None:
+            queues, latency, get_reads = self.slots[slot]
+            for (s, a), (qs, qa) in zip(queues, pending.queues):
+                np.copyto(s, qs)
+                np.copyto(a, qa)
+            np.copyto(latency, res.latency)
+            np.copyto(get_reads, res.get_reads)
+            self.reservoir.kept[slot] = Kept(
+                i, kmap, eng, queues, latency, get_reads,
+                program.job_arrays(pending.job_log),
+                program.stall_arrays(pending.stall_events),
+                res.shard_ids, self._calls())
+        return rec
+
+    def _make_slots(self, queues, n: int) -> None:
+        """One set of buffers per kept pass, written through once here so
+        that no page of them is first touched inside the window."""
+        def buf(size, dtype=np.float64):
+            b = np.empty(size, dtype)
+            b.fill(0)
+            return b
+        self.slots = [([(buf(q[0].shape[0]), buf(q[0].shape[0]))
+                        for q in queues], buf(n), buf(n, np.int32))
+                      for _ in self.reservoir.chosen]
+
+    def window(self, seconds: float) -> tuple[float, float]:
+        """Passes 1, 2, ... while under ``seconds``; returns the window's
+        start and end on the host clock."""
+        t0 = time.perf_counter()
+        i = 1
+        with self._span(WINDOW):
+            while time.perf_counter() - t0 < seconds:
+                try:
+                    self.passes.append(self.run_pass(i))
+                except Exception as e:  # a pass that fails ends the window
+                    self.failed += 1
+                    self.error = f"pass {i}: {type(e).__name__}: {e}"
+                    break
+                i += 1
+        return t0, time.perf_counter()
+
+    def close(self) -> None:
+        """Take the taps out of the program."""
+        for tap in self.taps.values():
+            tap.remove()
+        self.taps = {}
+
+    # ------------------------------------------------------------- check
+    def check(self, control: bool = False) -> list[tuple[str, float, float]]:
+        """Every number compared, with its limit: ``(name, value, limit)``.
+
+        For each kept pass: its store's merged view and a GET batch
+        against the latest writes of its stream; each tapped call against
+        its reference; its queues' arrivals against the stream's; its jobs'
+        times and its stalls against the device model; and every op's
+        latency against Lindley's recursion over queues the reference
+        builds itself (the stream's arrivals, and service by the device
+        model).  With ``control``, the latencies compared are the float32
+        reference's instead of the program's."""
+        kept = [k for k in self.reservoir.kept if k is not None]
+        device, store = self.conf["device"], self.conf["store"]
+        kpm = max(1, store["memtable_size"] // store["kv_size"])
+        view = gets = arrivals = sched = misplaced = 0
+        job_gap = gap = 0.0
+        differ = {n: 0 for n in self.taps}
+        for k in kept:
+            stream = self.base.mapped(k.key_map)
+            written = reference.latest_writes(stream.op_types, stream.keys)
+            view += _view_differs(k.engine.trees[0].merged_view(), written)
+            probe = _probe_keys(stream, self.traffic.probe_keys, self.seed,
+                                k.index)
+            got = np.asarray(k.engine.trees[0].get_batch(probe)[0])
+            gets += int(np.count_nonzero(
+                got != reference.get_answers(written, probe)))
+            for name, calls in k.calls.items():
+                check = reference.CALL_CHECKS[self.kernels[name].reference]
+                differ[name] += sum(check(a, o) for a, o in calls)
+            jobs = k.jobs
+            if jobs["t_start"].size:
+                job_gap = max(job_gap, float(np.max(np.abs(
+                    (jobs["t_finish"] - jobs["t_start"])
+                    - reference.job_seconds(jobs, device)))))
+            sched += reference.schedule_violations(jobs, device)
+            stall_ops, stalls = k.stalls
+            misplaced += int(np.count_nonzero(
+                ~np.isin(stall_ops, reference.fill_ops(stream.op_types, kpm))
+                | ~(stalls > 0)))
+            svc = reference.services(stream.op_types, stream.arrivals,
+                                     k.get_reads, jobs, stall_ops, stalls,
+                                     device, self.conf["service"])
+            for s, (_svc, arr) in enumerate(k.queues):
+                mine = slice(None) if k.shard_ids is None \
+                    else k.shard_ids == s
+                want_a = stream.arrivals[mine]
+                arrivals += int(np.count_nonzero(arr != want_a)) \
+                    if arr.shape == want_a.shape else int(want_a.shape[0])
+                ref = reference.departures(svc[mine], want_a) - want_a
+                if control:
+                    lat = reference.departures(svc[mine], want_a,
+                                               np.float32) - want_a
+                else:
+                    lat = k.latency[mine]
+                gap = max(gap, float(np.max(np.abs(lat - ref))))
+        missing = len(self.reservoir.chosen) - len(kept)
+        limits = self.traffic.limits
+        out = [("kept_passes_missing", missing, 0),
+               ("view_keys_differ", view, 0), ("gets_differ", gets, 0)]
+        out += [(f"{n}_calls_differ", v, 0) for n, v in differ.items()]
+        out += [("arrivals_differ", arrivals, 0),
+                ("schedule_violations", sched, 0),
+                ("stalls_misplaced", misplaced, 0),
+                ("job_time_gap_s", job_gap, float(limits["job_time_gap_s"])),
+                ("latency_gap_s", gap, float(limits["latency_gap_s"]))]
+        return out
+
+    def checked_counts(self) -> dict[str, int]:
+        kept = [k for k in self.reservoir.kept if k is not None]
+        out = {"passes": len(kept),
+               "jobs": sum(int(k.jobs["t_start"].size) for k in kept),
+               "stalls": sum(int(k.stalls[0].size) for k in kept)}
+        for n, tap in self.taps.items():
+            out[f"{n}_calls"] = sum(len(k.calls.get(n, ())) for k in kept)
+            out[f"{n}_calls_seen"] = tap.seen
+        return out
+
+
+def _pow2(n: int) -> int:
+    """The least power of two at or above ``n`` (1 for 0)."""
+    return 1 << max(0, int(n) - 1).bit_length()
+
+
+def _view_differs(view: dict, written: tuple[np.ndarray, np.ndarray]) -> int:
+    """Keys whose presence or sequence number differs between the store's
+    merged view and the reference."""
+    uk, useq = written
+    got_k = np.fromiter(view.keys(), np.int64, len(view))
+    got_s = np.fromiter(view.values(), np.int64, len(view))
+    both, i_ref, i_got = np.intersect1d(uk, got_k, assume_unique=True,
+                                        return_indices=True)
+    only = (uk.shape[0] - both.shape[0]) + (got_k.shape[0] - both.shape[0])
+    return int(only + np.count_nonzero(useq[i_ref] != got_s[i_got]))
+
+
+def _probe_keys(stream: Stream, n: int, seed: int, index: int) -> np.ndarray:
+    """Half keys of the stream, half keys drawn afresh (almost all
+    absent), from the seed."""
+    r = rng(seed, 606, index + 1)
+    present = stream.keys[r.integers(0, stream.n, n // 2)]
+    lo, hi = int(stream.keys.min()), int(stream.keys.max())
+    fresh = r.integers(lo, hi + 1, n - n // 2, dtype=np.int64)
+    return np.concatenate([present, fresh])
