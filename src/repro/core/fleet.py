@@ -60,6 +60,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from .. import obs
 from .lsm import Job
 from .sim import ChainScheduler, SimResult, Simulator, SlotPool
 from .types import DeviceModel, LSMConfig
@@ -116,6 +117,7 @@ class FleetEngine(Simulator):
     pass only.
     """
 
+    @obs.traced("fleet.structural")
     def prepare_structural(self, op_types: np.ndarray, keys: np.ndarray,
                            scan_lens: np.ndarray | None = None) -> None:
         """Phase A: replay every shard's op windows through the store and
@@ -180,6 +182,7 @@ class FleetEngine(Simulator):
         # on big matrices; only the gathered per-shard queues escape).
         self._svc_buf = np.empty_like(self._service0)
 
+    @obs.traced("fleet.plan_batch")
     def _plan_batch(self, drained: list[Job]) -> tuple:
         """Precompute the arrival-independent half of ``_schedule_jobs``
         for one drained batch: per-job durations, the chain-priority slot

@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from .. import obs
 from .sst import SST
 
 _BACKEND = "numpy"
@@ -71,6 +72,7 @@ def bloom_false_positives(keys: np.ndarray, bloom_seed,
     return (h.astype(np.float64) / _MAX32) < fpr
 
 
+@obs.traced("manifest.rank")
 def _rank(arr: np.ndarray, vals: np.ndarray, side: str,
           backend: str | None = None) -> np.ndarray:
     """Backend-routed searchsorted over a sorted int64 fence array.

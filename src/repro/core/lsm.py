@@ -26,6 +26,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from .. import obs
 from . import merge as merge_backend
 from .level_index import LevelIndex, bloom_false_positives
 from .memtable import Memtable
@@ -223,7 +224,7 @@ class LSMTree:
         compaction trigger.  ``flush_job`` depends on the chain's head (the
         L0 compaction) when one was needed *and* L0 was at the stop limit.
         """
-        with uid_allocator(self._sst_uids):
+        with obs.span("lsm.flush"), uid_allocator(self._sst_uids):
             return self._flush_immutable()
 
     def _flush_immutable(self) -> tuple[Job, list[Job]]:
@@ -280,6 +281,7 @@ class LSMTree:
             all_jobs.extend(jobs)
         return all_jobs
 
+    @obs.traced("lsm.chain")
     def _chain_pass(self, level: int, trigger: str
                     ) -> tuple[list[Job], list[int]]:
         """Run ONE compaction pass from ``level`` as a first-class chain:
@@ -362,6 +364,7 @@ class LSMTree:
         return jobs, stage_bytes
 
     # --- mechanism primitives (the strategy objects' toolbox) ---------------
+    @obs.traced("lsm.merge")
     def merge_runs(self, runs: list[tuple[np.ndarray, np.ndarray]]
                    ) -> tuple[np.ndarray, np.ndarray]:
         """Latest-wins k-way merge through the configured backend, with the
@@ -484,7 +487,7 @@ class LSMTree:
         exceed ``soft_limit_factor`` × target — trading I/O amplification
         (larger overlaps while overfull) for fewer stalls.
         """
-        with uid_allocator(self._sst_uids):
+        with obs.span("lsm.background"), uid_allocator(self._sst_uids):
             return self._background_triggers()
 
     def _background_triggers(self) -> list[Job]:

@@ -48,6 +48,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from .. import obs
 from ..analysis.sanitizer import maybe_sanitizer
 from .lsm import Job, LSMTree
 from .policies import get_policy
@@ -471,6 +472,7 @@ class Simulator:
         return unfinished[len(unfinished) - allowed] - t
 
     # ------------------------------------------------------------------
+    @obs.traced("sim.setup")
     def _setup(self, op_types: np.ndarray, keys: np.ndarray,
                arrivals: np.ndarray,
                scan_lens: np.ndarray | None) -> "_RunState":
@@ -760,6 +762,7 @@ class Simulator:
         shifted[1:] = s_cum[:-1]
         return float(s_cum[-1]), float(np.max(a - shifted))
 
+    @obs.traced("sim.apply_window")
     def _apply_window(self, shard: int, idx: np.ndarray,
                       op_types, keys, scan_lens, regions, get_reads,
                       get_probed, service, block_t: float) -> None:

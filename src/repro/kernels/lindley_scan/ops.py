@@ -15,6 +15,8 @@ from collections import OrderedDict
 
 import numpy as np
 
+from repro import obs
+
 from ..platform import bucket, interpret_mode
 from .kernel import BLOCK, LANES, NEG, lindley_scan_call
 
@@ -69,6 +71,7 @@ def clear_pad_plans() -> None:
     _np_scratch[1] = np.empty(0, np.float64)
 
 
+@obs.traced("lindley.batch")
 def lindley_batch_np(services: list[np.ndarray], arrivals: list[np.ndarray],
                      d0: list[float] | None = None,
                      backend: str = "pallas") -> list[np.ndarray]:
@@ -128,13 +131,16 @@ def lindley_batch_np(services: list[np.ndarray], arrivals: list[np.ndarray],
         return outs
     # bucket i by padded length: BLOCK * 2^ceil(log2(len/BLOCK)) — the
     # plan (bucket map + padded buffers) is cached across calls
+    obs.count("lindley.ops", sum(lens))
     out: list[np.ndarray | None] = [np.empty(0, np.float64)] * b
     for n_pad, idxs, S, A in _pad_plan(tuple(lens)):
-        for row, i in enumerate(idxs):
-            S[row, :lens[i]] = services[i]
-            A[row, :lens[i]] = arrivals[i]
-        D0 = np.asarray([d0[i] for i in idxs], np.float64)
+        with obs.span("lindley.fill"):
+            for row, i in enumerate(idxs):
+                S[row, :lens[i]] = services[i]
+                A[row, :lens[i]] = arrivals[i]
+            D0 = np.asarray([d0[i] for i in idxs], np.float64)
         dep = _pallas(S, A, D0) if backend == "pallas" else _jnp(S, A, D0)
+        obs.count("lindley.padded_ops", S.size)
         for row, i in enumerate(idxs):
             out[i] = dep[row, :lens[i]]
     return out
@@ -149,12 +155,18 @@ def _pairs(x: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
 
 def _pallas(S: np.ndarray, A: np.ndarray, D0: np.ndarray) -> np.ndarray:
     b, n = S.shape
-    planes = [p.reshape(b, n // LANES, LANES)
-              for p in (*_pairs(S), *_pairs(A))]
-    d0 = np.stack(_pairs(D0), axis=1).reshape(-1)
-    hi, lo = lindley_scan_call(d0, *planes, interpret=interpret_mode())
-    return (np.asarray(hi, np.float64) + np.asarray(lo, np.float64)
-            ).reshape(b, n)
+    with obs.span("lindley.split"):
+        planes = [p.reshape(b, n // LANES, LANES)
+                  for p in (*_pairs(S), *_pairs(A))]
+        d0 = np.stack(_pairs(D0), axis=1).reshape(-1)
+    with obs.span("lindley.call"):
+        out = lindley_scan_call(d0, *planes, interpret=interpret_mode())
+        hi, lo = (np.asarray(x, np.float64) for x in out)
+    if obs.enabled():
+        obs.count("lindley.h2d_bytes",
+                  d0.nbytes + sum(p.nbytes for p in planes))
+        obs.count("lindley.d2h_bytes", sum(x.nbytes for x in out))
+    return (hi + lo).reshape(b, n)
 
 
 def _jnp(S: np.ndarray, A: np.ndarray, D0: np.ndarray) -> np.ndarray:

@@ -6,6 +6,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import obs
+
 from ..platform import bucket, interpret_mode
 from .kernel import BLOCK, PLANES, SENTINEL, SUB, TILE, merge_path_call
 
@@ -56,7 +58,21 @@ def merge_two_runs_np(a_keys: np.ndarray, a_seqs: np.ndarray,
         return np.asarray(a_keys, np.int64), np.asarray(a_seqs, np.int64)
     if not (np.all(np.abs(a_seqs) < 2**31) and np.all(np.abs(b_seqs) < 2**31)):
         raise ValueError("merge_path carries seqnos as int32")
-    out = merge_path_call(_pack_run(a_keys, a_seqs), _pack_run(b_keys, b_seqs),
-                          interpret=interpret_mode())
-    planes = np.asarray(out).transpose(1, 0, 2).reshape(PLANES, -1)[:, :n + m]
-    return join_planes(planes[0], planes[1]), planes[2].astype(np.int64)
+    with obs.span("merge_path.pack"):
+        a, b = _pack_run(a_keys, a_seqs), _pack_run(b_keys, b_seqs)
+    # the kernel merges both buckets; the extra sentinel block of each
+    # packed run is shipped but not merged
+    padded, h2d = (a.shape[1] + b.shape[1] - 2) * BLOCK, a.nbytes + b.nbytes
+    with obs.span("merge_path.call"):
+        out = merge_path_call(a, b, interpret=interpret_mode())
+        del a, b                # free the packed runs before the copy back
+        out = np.asarray(out)
+    if obs.enabled():
+        obs.count("merge_path.calls")
+        obs.count("merge_path.keys", n + m)
+        obs.count("merge_path.padded_keys", padded)
+        obs.count("merge_path.h2d_bytes", h2d)
+        obs.count("merge_path.d2h_bytes", out.nbytes)
+    with obs.span("merge_path.unpack"):
+        planes = out.transpose(1, 0, 2).reshape(PLANES, -1)[:, :n + m]
+        return join_planes(planes[0], planes[1]), planes[2].astype(np.int64)

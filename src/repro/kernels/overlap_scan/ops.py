@@ -5,6 +5,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import obs
+
 from ..merge_path.ops import split_planes
 from ..platform import bucket, interpret_mode
 from .kernel import BLOCK, TILE, fence_rank_call
@@ -30,8 +32,17 @@ def fence_rank_np(fences: np.ndarray, keys: np.ndarray) -> np.ndarray:
     n_pad = bucket(keys.shape[0], BLOCK)
     k_hi, k_lo = (p.reshape(n_pad // TILE, TILE)
                   for p in _pad_planes(keys, n_pad))
-    out = fence_rank_call(f_hi, f_lo, k_hi, k_lo, interpret=interpret_mode())
-    return np.asarray(out).reshape(-1)[:keys.shape[0]]
+    with obs.span("fence_rank.call"):
+        out = np.asarray(fence_rank_call(f_hi, f_lo, k_hi, k_lo,
+                                         interpret=interpret_mode()))
+    if obs.enabled():
+        obs.count("fence_rank.calls")
+        obs.count("fence_rank.queries", keys.shape[0])
+        obs.count("fence_rank.padded_queries", n_pad)
+        obs.count("fence_rank.h2d_bytes", f_hi.nbytes + f_lo.nbytes
+                  + k_hi.nbytes + k_lo.nbytes)
+        obs.count("fence_rank.d2h_bytes", out.nbytes)
+    return out.reshape(-1)[:keys.shape[0]]
 
 
 def fence_rank_strict_np(fences: np.ndarray, keys: np.ndarray) -> np.ndarray:
