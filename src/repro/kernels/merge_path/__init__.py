@@ -1,4 +1,4 @@
 from . import ops, ref
-from .kernel import TILE, merge_path_call
+from .kernel import BLOCK, merge_path_call
 
-__all__ = ["TILE", "merge_path_call", "ops", "ref"]
+__all__ = ["BLOCK", "merge_path_call", "ops", "ref"]

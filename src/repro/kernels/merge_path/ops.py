@@ -9,7 +9,7 @@ import numpy as np
 from repro import obs
 
 from ..platform import bucket, interpret_mode
-from .kernel import BLOCK, PLANES, SENTINEL, SUB, TILE, merge_path_call
+from .kernel import BLOCK, LANES, PLANES, SENTINEL, SUB, merge_path_call
 
 
 def split_planes(keys: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
@@ -43,7 +43,7 @@ def _pack_run(keys: np.ndarray, seqs: np.ndarray) -> np.ndarray:
     buf[2, n:] = 0
     buf[0, :n], buf[1, :n] = split_planes(keys)
     buf[2, :n] = seqs
-    return buf.reshape(PLANES, total // BLOCK, SUB, TILE)
+    return buf.reshape(PLANES, total // BLOCK, SUB, LANES)
 
 
 def merge_two_runs_np(a_keys: np.ndarray, a_seqs: np.ndarray,
@@ -74,5 +74,5 @@ def merge_two_runs_np(a_keys: np.ndarray, a_seqs: np.ndarray,
         obs.count("merge_path.h2d_bytes", h2d)
         obs.count("merge_path.d2h_bytes", out.nbytes)
     with obs.span("merge_path.unpack"):
-        planes = out.transpose(1, 0, 2).reshape(PLANES, -1)[:, :n + m]
+        planes = out.reshape(PLANES, -1)[:, :n + m]
         return join_planes(planes[0], planes[1]), planes[2].astype(np.int64)

@@ -10,18 +10,52 @@ import jax  # noqa: E402
 
 
 # ------------------------------------------------------------- merge_path
-@pytest.mark.parametrize("n,m", [(1, 1), (7, 130), (128, 128), (257, 511),
-                                 (1000, 2500)])
-def test_merge_path(n, m):
+def _merge_case(kind, n, m, rng):
+    """Two sorted runs with seqnos.  ``random``: wide keys, two shared;
+    ``ties``: few distinct keys, so equal keys meet across and inside the
+    runs, B's seqnos *lower* than A's; ``equal``: every key the same."""
+    if kind == "random":
+        a = np.sort(rng.integers(-2**46, 2**46, n).astype(np.int64))
+        b = np.sort(rng.integers(-2**46, 2**46, m).astype(np.int64))
+        if n > 2 and m > 2:
+            b[:2] = a[:2]
+            b = np.sort(b)
+        return a, np.arange(n), b, np.arange(n, n + m)
+    if kind == "ties":
+        vals = rng.integers(-2**40, 2**40, max(2, (n + m) // 300))
+        a = np.sort(rng.choice(vals, n))
+        b = np.sort(rng.choice(vals, m))
+        return a, np.arange(m, m + n), b, np.arange(m)
+    assert kind == "equal"
+    a, b = np.full(n, 7 << 33, np.int64), np.full(m, 7 << 33, np.int64)
+    return a, np.arange(m, m + n)[::-1], b, np.arange(m)
+
+
+_SIZES = (1, 1023, 1024, 1025, 2047, 2048, 41_943)
+
+
+@pytest.mark.parametrize("kind,n,m", [
+    pytest.param("random", n, m, id=f"{n}-{m}")
+    for n, m in [(1, 1), (7, 130), (128, 128), (257, 511), (1000, 2500)]
+] + [
+    pytest.param("ties", n, m, id=f"ties-{n}-{m}")
+    for n, m in zip(_SIZES, _SIZES[::-1] + (1024,))
+] + [
+    pytest.param("ties", n, n, id=f"ties-{n}-{n}")
+    for n in _SIZES[1:] if n != 1025
+] + [
+    pytest.param("equal", 1025, 2047, id="equal-1025-2047"),
+    pytest.param("equal", 41_943, 1, id="equal-41943-1"),
+    # the RocksDB shape: a wide accumulated run against one small run
+    pytest.param("ties", 1_500_000, 41_943, id="ties-1500000-41943"),
+])
+def test_merge_path(kind, n, m):
+    """The kernel is an exact stable merge against ``np.argsort(kind=
+    "stable")``: A first on equal keys whatever the seqnos, duplicates
+    inside a run in their order, windows that straddle block and bucket
+    edges and land on the sentinel block."""
     from repro.kernels.merge_path import ops
-    rng = np.random.default_rng(n * 1000 + m)
-    a = np.sort(rng.integers(-2**46, 2**46, n).astype(np.int64))
-    b = np.sort(rng.integers(-2**46, 2**46, m).astype(np.int64))
-    if n > 2 and m > 2:
-        b[:2] = a[:2]
-        b = np.sort(b)
-    asq = np.arange(n, dtype=np.int64)
-    bsq = np.arange(n, n + m, dtype=np.int64)
+    a, asq, b, bsq = _merge_case(kind, n, m, np.random.default_rng(n * 1000 + m))
     k, s = ops.merge_two_runs_np(a, asq, b, bsq)
     kk = np.concatenate([a, b]); ss = np.concatenate([asq, bsq])
     order = np.argsort(kk, kind="stable")

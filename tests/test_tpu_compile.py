@@ -41,10 +41,11 @@ def _assert_kernel(compiled):
 
 
 @pytest.mark.parametrize("n_a,n_b", [(1024, 1024), (65536, 524288),
-                                     (524288, 524288)])
+                                     (524288, 524288), (65536, 262144)])
 def test_merge_path_compiles_for_v5e(one_chip, n_a, n_b):
     """A compaction merge: the largest smoke merge is one L1 group
-    (~47k keys) against an L2 slice of up to ~330k keys."""
+    (~47k keys) against an L2 slice of up to ~330k keys; the vLSM cell's
+    merges are one 41,943-key SST (bucket 65,536) against an L1 group."""
     from repro.kernels.merge_path.kernel import BLOCK, merge_path_call
     a = _shape(one_chip, (3, n_a // BLOCK + 1, 8, 128), jnp.int32)
     b = _shape(one_chip, (3, n_b // BLOCK + 1, 8, 128), jnp.int32)
