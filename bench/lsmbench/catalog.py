@@ -5,6 +5,9 @@
 * a kernel: ``bench/kernels/<kernel>.json``;
 * a metric: ``bench/metrics/<metric>.py``, whose ``read(readings)``
   returns the number, or None where it finds nothing to read;
+* a kernel's per-call reference: ``bench/references/<reference>.py``,
+  whose ``differs(args, out)`` says whether one call's answer differs
+  from the reference's;
 * chip peaks: ``bench/peaks.json``, keyed by JAX's ``device_kind``.
 
 Adding a cell, a mix, a kernel or a metric adds files and entries; no
@@ -90,11 +93,20 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def reader(metric: str):
-    """The ``read`` function of ``bench/metrics/<metric>.py``."""
-    path = BENCH / "metrics" / f"{metric}.py"
+def _module(kind: str, name: str):
+    path = BENCH / kind / f"{name}.py"
     spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_').replace('-', '_')}", path)
+        f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(metric: str):
+    """The ``read`` function of ``bench/metrics/<metric>.py``."""
+    return _module("metrics", metric).read
+
+
+def call_check(reference: str):
+    """The ``differs`` function of ``bench/references/<reference>.py``."""
+    return _module("references", reference).differs

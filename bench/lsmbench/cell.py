@@ -21,7 +21,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from . import program, reference
+from . import catalog, program, reference
 from .streams import Stream, Traffic, key_map, rng
 from .trace import WINDOW
 
@@ -39,7 +39,9 @@ class PassRecord:
 @dataclass
 class Kept:
     """A pass kept for the check: its store, what its temporal pass and
-    Lindley produced, and the map that made its stream from the seed's."""
+    Lindley produced, and the map that made its stream from the seed's.
+    ``get_probed`` (the files each read op opened) is kept for mixes with
+    SCANs only, whose service depends on it."""
 
     index: int
     key_map: tuple[int, int]
@@ -47,6 +49,7 @@ class Kept:
     queues: list
     latency: np.ndarray
     get_reads: np.ndarray
+    get_probed: np.ndarray | None
     jobs: dict
     stalls: tuple[np.ndarray, np.ndarray]
     shard_ids: np.ndarray | None
@@ -162,7 +165,8 @@ class Cell:
         self._arm(slot is not None)
         with self._span("structural"):
             eng = program.new_engine(self.cfg, self.device)
-            eng.prepare_structural(stream.op_types, stream.keys)
+            eng.prepare_structural(stream.op_types, stream.keys,
+                                   stream.scan_lens)
         phases["structural"] = time.perf_counter() - t0
         self._arm(False)
         t = time.perf_counter()
@@ -185,14 +189,16 @@ class Cell:
         if self.slots is None:
             self._make_slots(pending.queues, stream.n)
         if slot is not None:
-            queues, latency, get_reads = self.slots[slot]
+            queues, latency, get_reads, get_probed = self.slots[slot]
             for (s, a), (qs, qa) in zip(queues, pending.queues):
                 np.copyto(s, qs)
                 np.copyto(a, qa)
             np.copyto(latency, res.latency)
             np.copyto(get_reads, res.get_reads)
+            if get_probed is not None:
+                np.copyto(get_probed, res.get_probed)
             self.reservoir.kept[slot] = Kept(
-                i, kmap, eng, queues, latency, get_reads,
+                i, kmap, eng, queues, latency, get_reads, get_probed,
                 program.job_arrays(pending.job_log),
                 program.stall_arrays(pending.stall_events),
                 res.shard_ids, self._calls())
@@ -206,7 +212,8 @@ class Cell:
             b.fill(0)
             return b
         self.slots = [([(buf(q[0].shape[0]), buf(q[0].shape[0]))
-                        for q in queues], buf(n), buf(n, np.int32))
+                        for q in queues], buf(n), buf(n, np.int32),
+                       buf(n, np.int32) if self.traffic.scans else None)
                       for _ in self.reservoir.chosen]
 
     def window(self, seconds: float) -> tuple[float, float]:
@@ -242,24 +249,45 @@ class Cell:
         latency against Lindley's recursion over queues the reference
         builds itself (the stream's arrivals, and service by the device
         model).  With ``control``, the latencies compared are the float32
-        reference's instead of the program's."""
+        reference's instead of the program's.
+
+        A mix with SCANs adds a SCAN batch on each kept store against the
+        reference's answers, and each SCAN's delivered count: the one its
+        service in the program's queue implies (the service less the
+        reference's service of that SCAN, in keys at the read bandwidth)
+        against the reference's."""
         kept = [k for k in self.reservoir.kept if k is not None]
         device, store = self.conf["device"], self.conf["store"]
         kpm = max(1, store["memtable_size"] // store["kv_size"])
+        key_s = store["kv_size"] / device["read_bw"]
+        scans = self.traffic.scans
         view = gets = arrivals = sched = misplaced = 0
+        scans_differ = delivered_differ = 0
         job_gap = gap = 0.0
         differ = {n: 0 for n in self.taps}
         for k in kept:
             stream = self.base.mapped(k.key_map)
+            tree = k.engine.trees[0]
             written = reference.latest_writes(stream.op_types, stream.keys)
-            view += _view_differs(k.engine.trees[0].merged_view(), written)
-            probe = _probe_keys(stream, self.traffic.probe_keys, self.seed,
-                                k.index)
-            got = np.asarray(k.engine.trees[0].get_batch(probe)[0])
+            view += _view_differs(tree.merged_view(), written)
+            probe = _probe_keys(stream, self.traffic.probe_keys,
+                                rng(self.seed, 606, k.index + 1))
+            got = np.asarray(tree.get_batch(probe)[0])
             gets += int(np.count_nonzero(
                 got != reference.get_answers(written, probe)))
+            delivered = None
+            if scans:
+                r = rng(self.seed, 808, k.index + 1)
+                starts = _probe_keys(stream, self.traffic.probe_scans, r)
+                lens = r.integers(1, self.traffic.max_scan_length + 1,
+                                  starts.shape[0])
+                scans_differ += _scans_differ(
+                    program.scan(tree, starts, lens),
+                    reference.scan_answers(written, starts, lens))
+                delivered = reference.scan_delivered(
+                    stream.op_types, stream.keys, stream.scan_lens, kpm)
             for name, calls in k.calls.items():
-                check = reference.CALL_CHECKS[self.kernels[name].reference]
+                check = catalog.call_check(self.kernels[name].reference)
                 differ[name] += sum(check(a, o) for a, o in calls)
             jobs = k.jobs
             if jobs["t_start"].size:
@@ -273,8 +301,10 @@ class Cell:
                 | ~(stalls > 0)))
             svc = reference.services(stream.op_types, stream.arrivals,
                                      k.get_reads, jobs, stall_ops, stalls,
-                                     device, self.conf["service"])
-            for s, (_svc, arr) in enumerate(k.queues):
+                                     device, self.conf["service"],
+                                     k.get_probed, delivered,
+                                     store["kv_size"])
+            for s, (q_svc, arr) in enumerate(k.queues):
                 mine = slice(None) if k.shard_ids is None \
                     else k.shard_ids == s
                 want_a = stream.arrivals[mine]
@@ -287,10 +317,21 @@ class Cell:
                 else:
                     lat = k.latency[mine]
                 gap = max(gap, float(np.max(np.abs(lat - ref))))
+                if scans:
+                    sc = stream.op_types[mine] == reference.SCAN
+                    if q_svc.shape == want_a.shape:
+                        extra = (q_svc[sc] - svc[mine][sc]) / key_s
+                        delivered_differ += int(np.count_nonzero(
+                            np.rint(extra) != 0))
+                    else:
+                        delivered_differ += int(np.count_nonzero(sc))
         missing = len(self.reservoir.chosen) - len(kept)
         limits = self.traffic.limits
         out = [("kept_passes_missing", missing, 0),
                ("view_keys_differ", view, 0), ("gets_differ", gets, 0)]
+        if scans:
+            out += [("scans_differ", scans_differ, 0),
+                    ("scan_delivered_differ", delivered_differ, 0)]
         out += [(f"{n}_calls_differ", v, 0) for n, v in differ.items()]
         out += [("arrivals_differ", arrivals, 0),
                 ("schedule_violations", sched, 0),
@@ -304,6 +345,10 @@ class Cell:
         out = {"passes": len(kept),
                "jobs": sum(int(k.jobs["t_start"].size) for k in kept),
                "stalls": sum(int(k.stalls[0].size) for k in kept)}
+        if self.traffic.scans:
+            out["scans"] = len(kept) * int(np.count_nonzero(
+                self.base.op_types == reference.SCAN))
+            out["probe_scans"] = len(kept) * self.traffic.probe_scans
         for n, tap in self.taps.items():
             out[f"{n}_calls"] = sum(len(k.calls.get(n, ())) for k in kept)
             out[f"{n}_calls_seen"] = tap.seen
@@ -327,11 +372,30 @@ def _view_differs(view: dict, written: tuple[np.ndarray, np.ndarray]) -> int:
     return int(only + np.count_nonzero(useq[i_ref] != got_s[i_got]))
 
 
-def _probe_keys(stream: Stream, n: int, seed: int, index: int) -> np.ndarray:
+def _probe_keys(stream: Stream, n: int, r: np.random.Generator
+                ) -> np.ndarray:
     """Half keys of the stream, half keys drawn afresh (almost all
-    absent), from the seed."""
-    r = rng(seed, 606, index + 1)
+    absent), from ``r``."""
     present = stream.keys[r.integers(0, stream.n, n // 2)]
     lo, hi = int(stream.keys.min()), int(stream.keys.max())
     fresh = r.integers(lo, hi + 1, n - n // 2, dtype=np.int64)
     return np.concatenate([present, fresh])
+
+
+def _scans_differ(got, want) -> int:
+    """SCANs whose keys or sequence numbers differ: ``got`` and ``want``
+    are flattened ``(keys, seqs, offsets)``."""
+    g_k, g_s, g_off = got
+    w_k, w_s, w_off = want
+    n = w_off.shape[0] - 1
+    if g_off.shape != w_off.shape:
+        return n
+    bad = np.diff(g_off) != np.diff(w_off)
+    same = np.nonzero(~bad)[0]
+    cnt = np.diff(w_off)[same]
+    within = np.arange(int(cnt.sum())) - np.repeat(np.cumsum(cnt) - cnt, cnt)
+    gi = np.repeat(g_off[same], cnt) + within
+    wi = np.repeat(w_off[same], cnt) + within
+    wrong = (g_k[gi] != w_k[wi]) | (g_s[gi] != w_s[wi])
+    bad[np.repeat(same, cnt)[wrong]] = True
+    return int(np.count_nonzero(bad))

@@ -3,11 +3,11 @@
 The benchmark drives the store through its public phases
 (``FleetEngine.prepare_structural`` -> ``temporal_pass`` ->
 ``lindley_batch_np`` -> ``finalize``) and reads back the merged view, a
-GET batch, the result's job log and per-op latencies.  It asks for the
-device tier through the program's own switches where they still exist
-(``set_backend`` and ``backend=``); where a later version of the program
-has removed them, the platform's choice stands and nothing here needs to
-change.
+GET batch, a SCAN batch, the result's job log and per-op latencies.  It
+asks for the device tier through the program's own switches where they
+still exist (``set_backend`` and ``backend=``); where a later version of
+the program has removed them, the platform's choice stands and nothing
+here needs to change.
 
 Kernels are named by the files under ``bench/kernels/``: each gives the
 jitted entry whose compiled-shape count proves that the kernel ran
@@ -45,7 +45,7 @@ class Kernel:
     name: str
     entry: str        # jitted callable: its cache size counts compiled shapes
     host_entry: str   # numpy-in, numpy-out wrapper the check taps
-    reference: str    # name of the reference in ``reference.CALL_CHECKS``
+    reference: str    # ``bench/references/<reference>.py`` checks a call
     programs: tuple[str, ...]  # program names of its events in the trace
     # ``{"sizes_of_args": [i, ...], "args": ["sorted <axis>" | "count
     # <axis>", ...]}``: the arguments whose lengths are a call's sizes,
@@ -112,6 +112,18 @@ def lindley(services: list[np.ndarray],
 def new_engine(cfg, device):
     from repro.core import FleetEngine, UidNamespace
     return FleetEngine(cfg, device, uids=UidNamespace())
+
+
+def scan(tree, starts: np.ndarray, lengths: np.ndarray
+         ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """A batch of forward range scans on one tree, flattened:
+    ``(keys, seqs, offsets)``, scan ``i`` owning
+    ``offsets[i]:offsets[i + 1]``."""
+    res = tree.scan_batch(np.asarray(starts, np.int64),
+                          np.asarray(lengths, np.int32))
+    return (np.asarray(res.scan_keys, np.int64),
+            np.asarray(res.scan_seqs, np.int64),
+            np.asarray(res.scan_offsets, np.int64))
 
 
 def build_config(store: dict):

@@ -4,10 +4,14 @@ independently of it (this module imports nothing of the program).
 * A key-value store answers with the latest write: after a stream, each
   key written holds the sequence number of its last write, and a GET
   returns it (``-1`` for a key never written).  Sequence numbers count
-  the stream's writes from 0, in stream order.
-* A compaction merge of two sorted runs is a stable merge (ties: the
-  first, older run first).
-* A manifest fence rank counts the fences at or below each key.
+  the stream's writes from 0, in stream order.  A SCAN of length ``L``
+  from a start key returns the first ``L`` distinct keys written at or
+  above the start, in key order, each with the sequence number of its
+  last write.
+* Reads see writes by memtable window (one tree): the writes of a window
+  land before its reads, and a window ends at each op that fills a
+  memtable (every ``memtable_size / kv_size``-th write) and at the
+  stream's end.
 * The device model (the configuration's ``device`` and ``service``
   blocks): a background job holds the device for its bytes read and
   written at the stated bandwidths plus one I/O latency per SST read and
@@ -16,14 +20,19 @@ independently of it (this module imports nothing of the program).
   jobs it depends on have finished.  A PUT costs ``put_s`` of foreground
   service, a GET ``get_s`` plus one block time per block it reads, each
   block time inflated by ``busy_alpha`` for every compaction running
-  when it arrives; a write stall waits at the op that fills a memtable
-  and adds to its service.
+  when it arrives.  A SCAN costs ``scan_s``, one I/O latency (its seeks
+  go out at once), its delivered bytes at the read bandwidth and
+  ``scan_file_s`` per file it opens; the blocks it reads add their
+  transfer time times ``busy_alpha`` for every compaction running when
+  it arrives.  A write stall waits at the op that fills a memtable and
+  adds to its service.
 * Departures of a FIFO queue follow Lindley's recursion
   ``d_i = max(a_i, d_{i-1}) + s_i`` in float64, the precision the store
   states for its clock; a latency is ``d_i - a_i``.
 
 ``departures(..., np.float32)`` is the control: the same recursion one
-precision below the stated one.
+precision below the stated one.  The per-call references of the kernels
+are files of their own, ``bench/references/<name>.py``.
 """
 
 from __future__ import annotations
@@ -32,6 +41,7 @@ import numpy as np
 
 PUT = 0
 GET = 1
+SCAN = 3
 
 
 def latest_writes(op_types: np.ndarray, keys: np.ndarray
@@ -54,6 +64,44 @@ def get_answers(written: tuple[np.ndarray, np.ndarray],
     pos_c = np.minimum(pos, max(uk.shape[0] - 1, 0))
     hit = (pos < uk.shape[0]) & (uk[pos_c] == probe)
     return np.where(hit, useq[pos_c], -1)
+
+
+def scan_answers(written: tuple[np.ndarray, np.ndarray], starts: np.ndarray,
+                 lengths: np.ndarray
+                 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """What a SCAN from each start returns after the stream, flattened:
+    ``(keys, seqs, offsets)``, scan ``i`` owning
+    ``offsets[i]:offsets[i + 1]``."""
+    uk, useq = written
+    pos = np.searchsorted(uk, np.asarray(starts, np.int64), side="left")
+    counts = np.minimum(np.asarray(lengths, np.int64), uk.shape[0] - pos)
+    offsets = np.zeros(counts.shape[0] + 1, np.int64)
+    np.cumsum(counts, out=offsets[1:])
+    idx = np.repeat(pos - offsets[:-1], counts) + np.arange(offsets[-1])
+    return uk[idx], useq[idx], offsets
+
+
+def scan_delivered(op_types: np.ndarray, keys: np.ndarray,
+                   scan_lens: np.ndarray, memtable_keys: int) -> np.ndarray:
+    """For each SCAN of the stream, in stream order, how many keys it
+    returns: the distinct keys at or above its start that writes up to the
+    end of its memtable window have written, at most its length."""
+    sc = np.nonzero(op_types == SCAN)[0]
+    out = np.zeros(sc.shape[0], np.int64)
+    if not sc.shape[0]:
+        return out
+    fills = fill_ops(op_types, memtable_keys)
+    # the last op of each SCAN's window: the next fill, or the stream's end
+    last = np.append(fills, op_types.shape[0] - 1)[np.searchsorted(fills, sc)]
+    w = np.nonzero(op_types == PUT)[0]
+    uk, first = np.unique(keys[w], return_index=True)
+    born = w[first]                      # the op that first wrote each key
+    for h in np.unique(last):
+        mine = last == h
+        seen = uk[born <= h]
+        pos = np.searchsorted(seen, keys[sc[mine]], side="left")
+        out[mine] = np.minimum(scan_lens[sc[mine]], seen.shape[0] - pos)
+    return out
 
 
 def departures(service: np.ndarray, arrivals: np.ndarray,
@@ -109,50 +157,34 @@ def fill_ops(op_types: np.ndarray, memtable_keys: int) -> np.ndarray:
 
 def services(op_types: np.ndarray, arrivals: np.ndarray,
              get_reads: np.ndarray, jobs: dict, stall_ops: np.ndarray,
-             stalls: np.ndarray, device: dict, model: dict) -> np.ndarray:
+             stalls: np.ndarray, device: dict, model: dict,
+             files: np.ndarray | None = None,
+             delivered: np.ndarray | None = None,
+             kv_size: int = 0) -> np.ndarray:
     """Each op's foreground service by the device model, given the blocks
-    each GET read, the jobs' times and the stalls."""
+    each read op read, the jobs' times and the stalls; for SCANs also the
+    files each opened and the keys each delivered (one per SCAN, in
+    stream order)."""
     block = device["io_latency"] + device["block_size"] / device["read_bw"]
     comp = jobs["compact"]
     starts = np.sort(jobs["t_start"][comp])
     ends = np.sort(jobs["t_finish"][comp])
+
+    def running(at):
+        return (np.searchsorted(starts, at, side="right")
+                - np.searchsorted(ends, at, side="right"))
+
     svc = np.full(op_types.shape[0], model["put_s"], np.float64)
     g = np.nonzero(op_types == GET)[0]
-    busy = (np.searchsorted(starts, arrivals[g], side="right")
-            - np.searchsorted(ends, arrivals[g], side="right"))
     reads = get_reads[g].astype(np.float64)
     svc[g] = (model["get_s"] + reads * block
-              + reads * block * (model["busy_alpha"] * busy))
+              + reads * block * (model["busy_alpha"] * running(arrivals[g])))
+    sc = np.nonzero(op_types == SCAN)[0]
+    if sc.shape[0]:
+        svc[sc] = (model["scan_s"] + device["io_latency"]
+                   + delivered * float(kv_size) / device["read_bw"]
+                   + files[sc] * model["scan_file_s"]
+                   + get_reads[sc] * (device["block_size"] / device["read_bw"])
+                   * (model["busy_alpha"] * running(arrivals[sc])))
     np.add.at(svc, stall_ops, stalls)
     return svc
-
-
-def stable_merge(a_keys, a_seqs, b_keys, b_seqs):
-    keys = np.concatenate([np.asarray(a_keys, np.int64),
-                           np.asarray(b_keys, np.int64)])
-    seqs = np.concatenate([np.asarray(a_seqs, np.int64),
-                           np.asarray(b_seqs, np.int64)])
-    order = np.argsort(keys, kind="stable")
-    return keys[order], seqs[order]
-
-
-def rank_at_or_below(fences, keys):
-    return np.searchsorted(np.asarray(fences, np.int64),
-                           np.asarray(keys, np.int64), side="right")
-
-
-def _merge_differs(args, out) -> bool:
-    k, s = stable_merge(*args)
-    got_k, got_s = (np.asarray(x, np.int64) for x in out)
-    return not (np.array_equal(k, got_k) and np.array_equal(s, got_s))
-
-
-def _rank_differs(args, out) -> bool:
-    want = rank_at_or_below(*args)
-    got = np.asarray(out, np.int64).reshape(-1)
-    return not np.array_equal(want, got)
-
-
-#: per-call checks, by the ``reference`` name a kernel file gives
-CALL_CHECKS = {"stable_merge": _merge_differs,
-               "rank_at_or_below": _rank_differs}

@@ -11,11 +11,12 @@ from lsmbench import catalog, cli
 
 
 def small(name: str, records: int = 12_000, ops: int = 2_000,
-          sst_bytes: int = 1 << 17, rate: float | None = None
-          ) -> catalog.Workload:
+          sst_bytes: int = 1 << 17, rate: float | None = None,
+          fields: dict | None = None) -> catalog.Workload:
     """``name`` with ``records`` loaded, ``ops`` in the run phase and SSTs
     and memtables of ``sst_bytes``, the configuration's byte sizes scaled
-    alike; ``rate`` replaces the run phase's rate."""
+    alike; ``rate`` replaces the run phase's rate, and ``fields`` other
+    fields of the traffic."""
     config, mix = name.split(".", 1)
     wl = catalog.assemble(name, config, mix)
     conf = json.loads(json.dumps(wl.config))
@@ -24,7 +25,8 @@ def small(name: str, records: int = 12_000, ops: int = 2_000,
                             // store["sst_size"])
     store["memtable_size"] = store["sst_size"] = sst_bytes
     conf["record_count"] = records
-    traffic = dataclasses.replace(wl.traffic, operation_count=ops)
+    traffic = dataclasses.replace(wl.traffic, operation_count=ops,
+                                  **(fields or {}))
     if rate is not None:
         traffic = dataclasses.replace(traffic, run_rate_ops_s=rate)
     return dataclasses.replace(wl, config=conf, traffic=traffic)

@@ -2,6 +2,8 @@
 
 from __future__ import annotations
 
+import dataclasses
+import hashlib
 import json
 import re
 import shutil
@@ -70,14 +72,85 @@ def test_copied_generators_match_the_program():
     seed = 3_000_000_007
     pop = streams.load_keys(5_000, seed)
     np.testing.assert_array_equal(pop, workloads.load_keys(5_000, seed))
-    ops, keys = streams.read_update_mix(pop, 3_000, 0.5, 0.99, seed + 14)
+    ops, keys, lens = streams.ycsb_mix(pop, 3_000, 0.5, 0.0, 0.0, 1000,
+                                       0.99, seed + 14)
     spec = workloads.make_run_a(pop, 3_000, dist="zipfian", seed=seed + 14)
     np.testing.assert_array_equal(ops, spec.op_types)
     np.testing.assert_array_equal(keys, spec.keys)
+    assert not lens.any()
     load, run = db_bench._load_settle_run(5_000, 3_000, 2_500.0, 10.0)
     np.testing.assert_array_equal(
         streams.load_settle_run(5_000, 3_000, 1e6, 2_500.0, 10.0),
         np.concatenate([load, run]))
+
+
+def test_copied_scan_mix_matches_the_program():
+    from repro.bench_kv import workloads
+    seed = 3_000_000_011
+    pop = streams.load_keys(5_000, seed)
+    ops, keys, lens = streams.ycsb_mix(pop, 3_000, 0.0, 0.05, 0.95, 100,
+                                       0.99, seed + 14)
+    spec = workloads.make_run_e(pop, 3_000, dist="zipfian", seed=seed + 14,
+                                max_scan_len=100)
+    np.testing.assert_array_equal(ops, spec.op_types)
+    np.testing.assert_array_equal(keys, spec.keys)
+    np.testing.assert_array_equal(lens, spec.scan_lens)
+    assert (ops == streams.SCAN).sum() > 2_700 and lens.max() == 100
+
+
+def _digest(*arrays) -> str:
+    h = hashlib.blake2b(digest_size=16)
+    for a in arrays:
+        h.update(np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()
+
+
+def test_ycsb_a_stream_is_pinned():
+    """The read/update stream of ``ycsb-a.replay`` at a small size, as
+    the harness made it before SCANs and INSERTs came in."""
+    mix = dataclasses.replace(catalog.traffic("ycsb-a.replay"),
+                              operation_count=3_000)
+    s = mix.base_stream(5_000, 3_000_000_007)
+    assert s.op_types.dtype == np.uint8 and s.keys.dtype == np.int64
+    assert _digest(s.op_types, s.keys, s.arrivals) \
+        == "053363c022f6abd7a99a8b8a7cacc721"
+    assert s.scan_lens is None and not mix.scans and mix.probe_scans == 0
+
+
+def test_ycsb_e_traffic_file():
+    d = json.loads((ROOT / "bench" / "traffic" / "ycsb-e.replay.json")
+                   .read_text())
+    a = json.loads((ROOT / "bench" / "traffic" / "ycsb-a.replay.json")
+                   .read_text())
+    mix = catalog.traffic("ycsb-e.replay")
+    assert (mix.operation_count, mix.read_proportion, mix.scan_proportion,
+            mix.insert_proportion, mix.zipfian_theta, mix.max_scan_length,
+            mix.scan_length_distribution) == (200_000, 0.0, 0.95, 0.05, 0.99,
+                                              100, "uniform")
+    assert (mix.load_rate_ops_s, mix.settle_s, mix.run_rate_ops_s) \
+        == (1e6, 10.0, 2_500.0)
+    assert mix.kernels == ("merge_path", "fence_rank", "lindley_scan")
+    assert mix.probe_scans == 4_096
+    assert {k: v for k, v in d["check"].items() if k != "probe_scans"} \
+        == a["check"]
+    s = dataclasses.replace(mix, operation_count=2_000).base_stream(3_000, 9)
+    assert s.scan_lens.shape == s.op_types.shape
+    assert not s.scan_lens[:3_000].any()
+    run = s.op_types[3_000:]
+    assert set(np.unique(run)) == {streams.PUT, streams.SCAN}
+    assert (s.scan_lens[3_000:][run == streams.SCAN] >= 1).all()
+    moved = s.mapped(streams.key_map(9, 1))
+    assert moved.scan_lens is s.scan_lens
+
+
+def test_traffic_refuses_what_it_cannot_generate():
+    d = json.loads((ROOT / "bench" / "traffic" / "ycsb-e.replay.json")
+                   .read_text())
+    with pytest.raises(ValueError, match="uniform"):
+        streams.Traffic.from_json("x", dict(d, scan_length_distribution=
+                                            "zipfian"))
+    with pytest.raises(ValueError, match="over 1"):
+        streams.Traffic.from_json("x", dict(d, read_proportion=0.5))
 
 
 def test_pass_inputs_are_new_every_pass():
@@ -169,6 +242,100 @@ def test_reference_services_and_fill_ops():
     # the GET at 0.6 overlaps both compactions, the one at 2.5 none
     np.testing.assert_allclose(svc, [0.1, 0.2 + 2 * block * (1 + 0.5 * 2),
                                      0.1, 0.2 + block, 0.1, 0.6])
+
+
+def _scans_by_loop(op_types, keys, lens, kpm):
+    """SCANs the plain way: writes land in a dict as they come, reads wait
+    for the end of their memtable window (every ``kpm``-th write)."""
+    store, seq, writes, waiting, counts = {}, 0, 0, [], []
+
+    def resolve():
+        for start, length in waiting:
+            counts.append(len(sorted(k for k in store if k >= start)[:length]))
+        waiting.clear()
+
+    for op, key, length in zip(op_types.tolist(), keys.tolist(),
+                               lens.tolist()):
+        if op == 0:
+            store[key], seq, writes = seq, seq + 1, writes + 1
+            if writes % kpm == 0:
+                resolve()
+        elif op == 3:
+            waiting.append((key, length))
+    resolve()
+    return store, counts
+
+
+def test_reference_scans_against_a_loop():
+    from lsmbench import reference
+    # windows of 3 writes: ops 0-3, 4-8, then 9-11 to the stream's end;
+    # key 10 is overwritten, 50 is inserted after SCANs of its window and
+    # 40 after one of an earlier window, 99 starts past every key
+    op_types = np.array([0, 3, 0, 0, 3, 0, 3, 0, 0, 3, 3, 0], np.uint8)
+    keys = np.array([10, 12, 20, 30, 15, 25, 99, 10, 40, 5, 26, 50],
+                    np.int64)
+    lens = np.array([0, 3, 0, 0, 5, 0, 3, 0, 0, 2, 10, 0], np.int32)
+    store, want = _scans_by_loop(op_types, keys, lens, 3)
+    got = reference.scan_delivered(op_types, keys, lens, 3)
+    assert got.tolist() == want == [2, 4, 0, 2, 3]
+    written = reference.latest_writes(op_types, keys)
+    starts = np.array([0, 26, 41, 99, 10, 51], np.int64)
+    lengths = np.array([2, 10, 1, 4, 3, 1])
+    k, sq, off = reference.scan_answers(written, starts, lengths)
+    for i, (start, length) in enumerate(zip(starts, lengths)):
+        top = sorted(x for x in store if x >= start)[:length]
+        assert k[off[i]:off[i + 1]].tolist() == top
+        assert sq[off[i]:off[i + 1]].tolist() == [store[x] for x in top]
+    assert np.diff(off).tolist() == [2, 3, 1, 0, 3, 0]
+    assert store[10] == 4
+
+
+def test_reference_scan_services_match_the_program():
+    """The SCAN terms of the reference's service, against the service the
+    store puts in its queue, on a small YCSB-E stream whose run phase
+    arrives while the load's compactions still run."""
+    from lsmbench import reference
+    from smallcell import small
+    wl = small("vlsm-8m.ycsb-e.replay", records=6_000, ops=1_000)
+    mix = dataclasses.replace(wl.traffic, settle_s=0.0, run_rate_ops_s=1e6)
+    conf, store = wl.config, wl.config["store"]
+    s = mix.base_stream(conf["record_count"], 5_000_000_029)
+    eng = program.new_engine(program.build_config(store),
+                             program.build_device(conf["device"]))
+    eng.prepare_structural(s.op_types, s.keys, s.scan_lens)
+    pending = eng.temporal_pass(s.arrivals)
+    res = eng.finalize([reference.departures(q, a)
+                        for q, a in pending.queues], pending=pending)
+    jobs = program.job_arrays(pending.job_log)
+    kpm = store["memtable_size"] // store["kv_size"]
+    svc = reference.services(
+        s.op_types, s.arrivals, res.get_reads, jobs,
+        *program.stall_arrays(pending.stall_events), conf["device"],
+        conf["service"], res.get_probed,
+        reference.scan_delivered(s.op_types, s.keys, s.scan_lens, kpm),
+        store["kv_size"])
+    sc = s.op_types == reference.SCAN
+    np.testing.assert_allclose(svc, pending.queues[0][0], rtol=1e-12, atol=0)
+    comp = jobs["compact"]
+    running = (np.searchsorted(np.sort(jobs["t_start"][comp]),
+                               s.arrivals[sc], side="right")
+               - np.searchsorted(np.sort(jobs["t_finish"][comp]),
+                                 s.arrivals[sc], side="right"))
+    assert (running > 0).any() and (res.get_probed[sc] > 0).all()
+    assert (res.get_reads[sc] > 0).all()
+
+
+def test_per_call_checks_are_files_named_by_the_kernels():
+    for name in ("merge_path", "fence_rank"):
+        kernel = program.Kernel.load(catalog.BENCH, name)
+        assert callable(catalog.call_check(kernel.reference))
+    merge = catalog.call_check("stable_merge")
+    args = ([1, 3], [10, 11], [1, 2], [20, 21])
+    assert not merge(args, ([1, 1, 2, 3], [10, 20, 21, 11]))
+    assert merge(args, ([1, 1, 2, 3], [20, 10, 21, 11]))  # B first on a tie
+    rank = catalog.call_check("rank_at_or_below")
+    assert not rank(([5, 9], [4, 5, 9, 10]), np.array([0, 1, 2, 2]))
+    assert rank(([5, 9], [4, 5, 9, 10]), np.array([0, 0, 2, 2]))
 
 
 # ------------------------------------------------------- the byte counts

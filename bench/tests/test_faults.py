@@ -13,13 +13,17 @@ place) are here too.  The faults a one-chip cell of this store can have:
 * an answer altered where it is produced: one sequence number of a merge,
   one fence rank, one departure; in the temporal pass, two arrivals
   swapped in a queue, jobs timed without their per-SST latency, and GETs
-  served without the inflation of the compactions they overlap.
+  served without the inflation of the compactions they overlap;
+* on a mix with SCANs (``ycsb-e.replay``): a SCAN one key short, SCANs
+  that skip the memtable, SCAN service without its seek wave, and the
+  SCAN lengths lost on the way into the store.
 
 No cell has an exchange between chips.
 """
 
 from __future__ import annotations
 
+import ast
 import importlib
 from contextlib import contextmanager
 
@@ -30,12 +34,27 @@ from smallcell import run_small, small
 
 REPLAY = "vlsm-8m.ycsb-a.replay"
 ROCKS = "rocksdb-64m.ycsb-a.replay"
+SCANS = "vlsm-8m.ycsb-e.replay"
+#: the SCAN cell cut for the interpreter: each SCAN merges its runs
+#: through the interpreted merge kernel (about 10 ms a SCAN), so one kept
+#: pass, one warm-up pass and a SCAN batch of 256
+SCANS_SMALL = {"keep_passes": 1, "keep_among": 1, "warm_passes": 1,
+               "probe_scans": 256}
+#: every number the check compares, in order, for a mix without SCANs
+CHECKS = ["kept_passes_missing", "view_keys_differ", "gets_differ",
+          "merge_path_calls_differ", "fence_rank_calls_differ",
+          "arrivals_differ", "schedule_violations", "stalls_misplaced",
+          "job_time_gap_s", "latency_gap_s"]
 MERGE = "repro.kernels.merge_path.ops:merge_two_runs_np"
 RANK = "repro.kernels.overlap_scan.ops:fence_rank_np"
 LINDLEY = "repro.kernels.lindley_scan.ops:lindley_batch_np"
 TEMPORAL = "repro.core.fleet:FleetEngine.temporal_pass"
 JOB_TIME = "repro.core.sim:Simulator._job_duration"
 BUSY = "repro.core.sim:Simulator._busy_inflation"
+SCAN_IMPL = "repro.core.lsm:LSMTree._scan_impl"
+MEMTABLE_SCAN = "repro.core.memtable:Memtable.scan_from"
+APPLY_WINDOW = "repro.core.sim:Simulator._apply_window"
+SETUP = "repro.core.sim:Simulator._setup"
 
 
 @contextmanager
@@ -123,12 +142,59 @@ def no_busy_inflation(orig):
     return f
 
 
+def scan_one_short(orig):
+    def f(self, start_keys, lengths):
+        counts, blocks, files, keys, seqs = orig(self, start_keys, lengths)
+        keep = np.ones(keys.shape[0], bool)
+        keep[np.cumsum(counts)[counts > 0] - 1] = False
+        return np.maximum(counts - 1, 0), blocks, files, keys[keep], seqs[keep]
+    return f
+
+
+def scan_skips_memtable(orig):
+    def f(self, key, m):
+        return np.empty(0, np.int64), np.empty(0, np.int64), False
+    return f
+
+
+def scan_without_seek_wave(orig):
+    def f(self, shard, idx, op_types, keys, scan_lens, regions, get_reads,
+          get_probed, service, block_t):
+        orig(self, shard, idx, op_types, keys, scan_lens, regions,
+             get_reads, get_probed, service, block_t)
+        service[idx[op_types[idx] == 3]] -= self.device.io_latency
+    return f
+
+
+def scan_lens_dropped(orig):
+    """The lengths lost on the way in: every SCAN asks for the least the
+    store takes, one key."""
+    def f(self, op_types, keys, arrivals, scan_lens):
+        if scan_lens is not None:
+            scan_lens = np.minimum(scan_lens, 1)
+        return orig(self, op_types, keys, arrivals, scan_lens)
+    return f
+
+
 @pytest.mark.parametrize("cell", [REPLAY, ROCKS])
 def test_sound_run_is_correct(cell):
     out = run_small(cell)
     assert out["correct"], out["check"]
     assert out["attempted"] >= 1 and out["failed"] == 0
-    assert list(out["check"])[-1] == "latency_gap_s"
+    assert list(out["check"]) == CHECKS
+
+
+def test_sound_scan_run_is_correct(capsys):
+    out = run_small(SCANS, seconds=1.0, fields=SCANS_SMALL)
+    assert out["correct"], out["check"]
+    assert out["attempted"] >= 1 and out["failed"] == 0
+    assert list(out["check"]) == (CHECKS[:3] + ["scans_differ",
+                                                "scan_delivered_differ"]
+                                  + CHECKS[3:])
+    line = [x for x in capsys.readouterr().err.splitlines()
+            if x.startswith("checked: ")][-1]
+    counted = ast.literal_eval(line[len("checked: "):])
+    assert counted["scans"] > 1_000 and counted["probe_scans"] == 256
 
 
 @pytest.mark.parametrize("cell,path,fault", [
@@ -147,6 +213,19 @@ def test_fault_is_caught(cell, path, fault):
     with planted(path, fault):
         out = run_small(cell)
     assert not out["correct"], out
+
+
+@pytest.mark.parametrize("path,fault,number", [
+    (SCAN_IMPL, scan_one_short, "scans_differ"),
+    (MEMTABLE_SCAN, scan_skips_memtable, "scans_differ"),
+    (APPLY_WINDOW, scan_without_seek_wave, "scan_delivered_differ"),
+    (SETUP, scan_lens_dropped, "scan_delivered_differ"),
+])
+def test_scan_fault_is_caught(path, fault, number):
+    with planted(path, fault):
+        out = run_small(SCANS, seconds=1.0, fields=SCANS_SMALL)
+    assert not out["correct"], out
+    assert out["check"][number]["value"] > out["check"][number]["limit"]
 
 
 def test_control_fails_where_the_program_passes():
