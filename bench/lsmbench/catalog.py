@@ -3,6 +3,10 @@
 * a configuration: ``bench/configs/<config>.json``;
 * a traffic mix: ``bench/traffic/<traffic>.json``;
 * a kernel: ``bench/kernels/<kernel>.json``;
+* the kernels of a cell: those its traffic mix names, then, without
+  repeats, those ``bench/cells/<cell>.json`` names under ``"kernels"``,
+  where that file exists: a kernel that only one cell's program uses (a
+  cell entry of ``BENCHMARK.json`` takes no keys beyond its contract's);
 * a metric: ``bench/metrics/<metric>.py``, whose ``read(readings)``
   returns the number, or None where it finds nothing to read;
 * a kernel's per-call reference: ``bench/references/<reference>.py``,
@@ -55,8 +59,22 @@ def _reports(metric: dict, cell: str, e2e_of_cell: set[str]) -> bool:
     return True
 
 
-def traffic(name: str) -> Traffic:
-    return Traffic.from_json(name, _json(BENCH / "traffic" / f"{name}.json"))
+def _bench(root: Path) -> Path:
+    return root / BENCH.relative_to(ROOT)
+
+
+def traffic(name: str, root: Path = ROOT) -> Traffic:
+    return Traffic.from_json(
+        name, _json(_bench(root) / "traffic" / f"{name}.json"))
+
+
+def _kernel_names(cell: str, mix: Traffic, root: Path = ROOT
+                 ) -> tuple[str, ...]:
+    """The kernels of the cell ``cell`` on the mix ``mix``: the mix's,
+    then those its cell file adds, each once."""
+    path = _bench(root) / "cells" / f"{cell}.json"
+    extra = _json(path)["kernels"] if path.exists() else []
+    return tuple(dict.fromkeys([*mix.kernels, *extra]))
 
 
 def workload(name: str, root: Path = ROOT) -> Workload:
@@ -72,13 +90,14 @@ def workload(name: str, root: Path = ROOT) -> Workload:
 def assemble(name: str, config_name: str, traffic_name: str, chips: int = 1,
              root: Path = ROOT) -> Workload:
     """A cell from a configuration and a traffic mix by their names; the
-    metrics are those ``BENCHMARK.json`` gives the cell ``name``."""
+    kernels are those of the mix and of the cell file of ``name``, the
+    metrics those ``BENCHMARK.json`` gives the cell ``name``."""
     bm = benchmark(root)
     confs = {c["name"]: c for c in bm["configs"]}
     conf = _json(root / confs[config_name]["file"])
-    mix = traffic(traffic_name)
-    kernels = {k: Kernel.load(BENCH, k)
-               for k in mix.kernels}
+    mix = traffic(traffic_name, root)
+    kernels = {k: Kernel.load(_bench(root), k)
+               for k in _kernel_names(name, mix, root)}
     e2e = [m for m in bm["end_to_end"] if _reports(m, name, set())]
     e2e_names = {m["name"] for m in e2e}
     per_layer = [m for m in bm["per_layer"] if _reports(m, name, e2e_names)]

@@ -10,12 +10,20 @@ The window rule: passes start while the elapsed time is under the
 window's length; the window ends when the last pass that started ends.
 The harness keeps a few passes for the check, drawn from the seed, and
 with them a seeded sample of each tapped kernel's calls in those passes.
+
+The pass budget: no pass, warm-up or timed, may run longer than
+``pass_budget`` of the window.  A program slower than that cannot serve
+the cell at its size, and the run ends (see ``budget``).
 """
 
 from __future__ import annotations
 
 import contextlib
 import itertools
+import os
+import signal
+import sys
+import threading
 import time
 from dataclasses import dataclass, field
 
@@ -24,6 +32,66 @@ import numpy as np
 from . import catalog, program, reference
 from .streams import Stream, Traffic, key_map, rng
 from .trace import WINDOW
+
+
+# Seconds past its budget after which a pass that never hands control back
+# to the interpreter (so that the budget's signal handler cannot run) ends
+# the process.
+BACKSTOP_S = 30.0
+
+
+def pass_budget(seconds: float) -> float:
+    """The longest a pass may take in a run whose window is ``seconds``
+    long: twice the window, and never under 60 s.  A pass longer than
+    twice the window leaves a window of at most one pass, which gives no
+    steady ``ops_per_s``; the floor leaves short windows (the harness's
+    CPU tests) room for passes of interpreted kernels."""
+    return max(2.0 * seconds, 60.0)
+
+
+class OverBudget(BaseException):
+    """A pass ran past its budget.  Not an ``Exception``, so that no
+    handler inside the program under test can swallow it."""
+
+
+@contextlib.contextmanager
+def budget(label: str, seconds: float, phase):
+    """Interrupts the body once it has run ``pass_budget(seconds)``: an
+    interval timer raises ``OverBudget`` in the main thread, naming
+    ``label``, the phase ``phase()`` returns and the seconds elapsed.
+    Where the interpreter never regains control, a backstop thread prints
+    the same message and ends the process ``BACKSTOP_S`` later.  Both are
+    disarmed when the body ends, so neither can fire outside it."""
+    limit = pass_budget(seconds)
+    t0 = time.perf_counter()
+
+    def message() -> str:
+        return (f"{label} ran past its budget of {limit:g} s (twice the "
+                f"{seconds:g} s window, at least 60 s) in phase {phase()}, "
+                f"{time.perf_counter() - t0:.1f} s in: the program cannot "
+                f"serve this cell at its size")
+
+    def backstop() -> None:
+        print(f"bench: {message()}; the interpreter never regained "
+              f"control", file=sys.stderr, flush=True)
+        os._exit(3)
+
+    stop = threading.Timer(limit + BACKSTOP_S, backstop)
+    stop.daemon = True
+
+    def on_alarm(_signum, _frame):
+        stop.cancel()      # the interpreter has control: no backstop
+        raise OverBudget(message())
+
+    old = signal.signal(signal.SIGALRM, on_alarm)
+    stop.start()
+    signal.setitimer(signal.ITIMER_REAL, limit)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        stop.cancel()
+        signal.signal(signal.SIGALRM, old)
 
 
 @dataclass
@@ -101,6 +169,7 @@ class Cell:
         self.passes: list[PassRecord] = []
         self.failed = 0
         self.error = ""
+        self.phase = ""
 
     # ----------------------------------------------------------- phases
     def _span(self, name: str):
@@ -118,15 +187,18 @@ class Cell:
     def _calls(self) -> dict[str, list]:
         return {n: t.calls for n, t in self.taps.items()}
 
-    def setup(self) -> None:
+    def setup(self, seconds: float) -> None:
         """The seed's stream, the warm-up passes (each over a map of its
-        own), then the size ladder, so that every program the window uses
-        is compiled or loaded before it starts."""
+        own, each within the budget of a ``seconds`` window: past it,
+        ``OverBudget``), then the size ladder, so that every program the
+        window uses is compiled or loaded before it starts."""
         self.base = self.traffic.base_stream(self.record_count, self.seed)
         for tap in self.taps.values():
             tap.sizes = set() if tap.kernel.warm else None
-        self.warm = [self.run_pass(-w, keep=False)
-                     for w in range(self.traffic.warm_passes)]
+        self.warm = []
+        for w in range(self.traffic.warm_passes):
+            with budget(f"warm-up pass {-w}", seconds, lambda: self.phase):
+                self.warm.append(self.run_pass(-w, keep=False))
         t = time.perf_counter()
         self.ladder_calls = self._warm_ladder()
         self.ladder_s = time.perf_counter() - t
@@ -157,28 +229,34 @@ class Cell:
         return n
 
     def run_pass(self, i: int, keep: bool = True) -> PassRecord:
+        self.phase = "structural"
         slot = self.reservoir.slot(i) if keep else None
         t0 = time.perf_counter()
         phases = {}
         kmap = key_map(self.seed, i)
         stream = self.base.mapped(kmap)
         self._arm(slot is not None)
-        with self._span("structural"):
-            eng = program.new_engine(self.cfg, self.device)
-            eng.prepare_structural(stream.op_types, stream.keys,
-                                   stream.scan_lens)
+        try:
+            with self._span("structural"):
+                eng = program.new_engine(self.cfg, self.device)
+                eng.prepare_structural(stream.op_types, stream.keys,
+                                       stream.scan_lens)
+        finally:
+            self._arm(False)
         phases["structural"] = time.perf_counter() - t0
-        self._arm(False)
         t = time.perf_counter()
+        self.phase = "temporal"
         with self._span("temporal"):
             pending = eng.temporal_pass(stream.arrivals)
         phases["temporal"] = time.perf_counter() - t
         t = time.perf_counter()
+        self.phase = "lindley"
         with self._span("lindley"):
             deps = program.lindley([q[0] for q in pending.queues],
                                    [q[1] for q in pending.queues])
         phases["lindley"] = time.perf_counter() - t
         t = time.perf_counter()
+        self.phase = "finalize"
         with self._span("finalize"):
             res = eng.finalize(deps, pending=pending)
         t1 = time.perf_counter()
@@ -217,15 +295,17 @@ class Cell:
                       for _ in self.reservoir.chosen]
 
     def window(self, seconds: float) -> tuple[float, float]:
-        """Passes 1, 2, ... while under ``seconds``; returns the window's
-        start and end on the host clock."""
+        """Passes 1, 2, ... while under ``seconds``, each within its
+        budget; returns the window's start and end on the host clock."""
         t0 = time.perf_counter()
         i = 1
         with self._span(WINDOW):
             while time.perf_counter() - t0 < seconds:
                 try:
-                    self.passes.append(self.run_pass(i))
-                except Exception as e:  # a pass that fails ends the window
+                    with budget(f"pass {i}", seconds, lambda: self.phase):
+                        self.passes.append(self.run_pass(i))
+                # a pass that fails or runs past its budget ends the window
+                except (Exception, OverBudget) as e:
                     self.failed += 1
                     self.error = f"pass {i}: {type(e).__name__}: {e}"
                     break

@@ -3,8 +3,10 @@
 Order of a run: find the cell's files; point JAX's persistent compilation
 cache at its fixed directory; refuse anything but a TPU with the chips the
 cell asks for, and kernels that would run interpreted; set up (the
-stream and the warm-up passes); measure the window
-(under the profiler with ``--trace 1``); read the device's memory peak;
+stream and the warm-up passes), refusing a warm-up pass that runs past
+the pass budget (``cell.pass_budget``: twice the window, at least 60 s);
+measure the window (under the profiler with ``--trace 1``), where a pass
+past its budget fails and ends the window; read the device's memory peak;
 refuse a kernel the cell needs that never ran compiled; check what the
 window produced against the reference; print the metrics.
 """
@@ -21,7 +23,7 @@ from dataclasses import dataclass
 from pathlib import Path
 
 from . import catalog, program, trace
-from .cell import Cell, PassRecord
+from .cell import Cell, OverBudget, PassRecord
 
 CACHE_DIR = catalog.BENCH / ".jax_cache"
 
@@ -126,7 +128,10 @@ def run(argv, t_start: float, require_tpu: bool = True,
 
     cell = Cell(wl.name, wl.config, wl.traffic, wl.kernels, seed,
                 annotate=bool(args.trace))
-    cell.setup()
+    try:
+        cell.setup(args.seconds)
+    except OverBudget as e:
+        raise Refused(str(e)) from None
     compiles_before = log.count
     setup_s = time.perf_counter() - t_start
     print(f"setup: {setup_s:.4f} s, {compiles_before} programs compiled or "

@@ -43,7 +43,7 @@ def main(argv=None) -> int:
         t0 = time.perf_counter()
         cell = Cell(wl.name, wl.config, wl.traffic, wl.kernels, seed)
         try:
-            cell.setup()
+            cell.setup(args.seconds)
             cell.window(args.seconds)
             sound = {n: v for n, v, _lim in cell.check()}
             control = {n: v for n, v, _lim in cell.check(control=True)}

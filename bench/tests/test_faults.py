@@ -237,7 +237,7 @@ def test_control_fails_where_the_program_passes():
     program.select_device_tier()
     c = cell_mod.Cell(wl.name, wl.config, wl.traffic, wl.kernels, 11)
     try:
-        c.setup()
+        c.setup(2.0)
         c.window(2.0)
         sound = dict((n, (v, lim)) for n, v, lim in c.check())
         control = dict((n, (v, lim)) for n, v, lim in c.check(control=True))

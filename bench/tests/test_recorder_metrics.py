@@ -47,11 +47,13 @@ def _read_all(r) -> dict:
 
 
 def test_every_reader_is_a_per_layer_metric_of_both_cells():
+    """Both YCSB-A replay cells report every reader; a cell added later
+    need not."""
     entries = {m["name"]: m for m in catalog.benchmark()["per_layer"]}
-    cells = [w["name"] for w in catalog.benchmark()["workloads"]]
+    cells = {"vlsm-8m.ycsb-a.replay", "rocksdb-64m.ycsb-a.replay"}
     for m in METRICS:
         assert entries[m]["moves"] == "ops_per_s"
-        assert entries[m]["workloads"] == cells
+        assert cells <= set(entries[m]["workloads"])
 
 
 def _spans(clock: _Clock, tree: tuple) -> None:
