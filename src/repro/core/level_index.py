@@ -21,10 +21,17 @@ Rank queries are backend-switchable, mirroring ``repro.core.merge``:
 * ``pallas`` — the ``repro.kernels.overlap_scan`` fence-rank TPU kernel
                (interpreted off the TPU); parity tests prove it drop-in.
 
-Every query reduces to two rank primitives over sorted int64 fences:
-``rank_left(a, v) = #{a < v}`` and ``rank_right(a, v) = #{a <= v}``; the SSTs
+Every query reduces to rank primitives over sorted int64 fences:
+``rank_left(a, v) = #{a < v}`` and ``rank_right(a, v) = #{a <= v}``.  The SSTs
 of a sorted disjoint level intersecting ``[lo, hi]`` are exactly positions
-``[rank_left(largest, lo), rank_right(smallest, hi))``.
+``[rank_left(largest, lo), rank_right(smallest, hi))``, and one inclusive rank
+answers both ends.  The level's interleaved fence table ``F = [s0, l0, s1, l1,
+...]`` is non-decreasing, since ``s_i <= l_i < s_{i+1}``, so with ``r =
+rank_right(F, v)`` the level holds ``ceil(r / 2)`` smallest fences and
+``floor(r / 2)`` largest fences at or below ``v``.  Keys are integers, so
+``rank_left(largest, lo) = rank_right(F, lo - 1) // 2`` and
+``rank_right(smallest, hi) = (rank_right(F, hi) + 1) // 2``: one rank of the
+queries ``lo - 1`` and ``hi`` together, one device round trip on ``pallas``.
 """
 
 from __future__ import annotations
@@ -128,6 +135,7 @@ class LevelIndex:
         self.uids = [z() for _ in range(n_levels)]
         self.bloom = [np.empty(0, np.uint64) for _ in range(n_levels)]
         self._csum: list[np.ndarray | None] = [None] * n_levels
+        self._table: list[np.ndarray | None] = [None] * n_levels
         # Per-level mutation counter: bumps on every structural update so
         # derived caches (the tree's flat key/seq concatenation feeding
         # the vectorized GET path) can invalidate lazily.
@@ -141,6 +149,7 @@ class LevelIndex:
         self.uids[level] = uids
         self.bloom[level] = (uids.astype(np.uint64) * _UID_MIX)
         self._csum[level] = None
+        self._table[level] = None
         self.version[level] += 1
 
     def refresh(self, level: int, ssts: list[SST]) -> None:
@@ -189,13 +198,27 @@ class LevelIndex:
         """(smallest, largest) fence arrays of a sorted, disjoint level."""
         return self.smallest[level], self.largest[level]
 
+    def fence_table(self, level: int) -> np.ndarray:
+        """The interleaved fences ``[s0, l0, s1, l1, ...]`` of a sorted,
+        disjoint level: non-decreasing, so one rank array (cached)."""
+        if self._table[level] is None:
+            table = np.empty(2 * self.n_ssts(level), np.int64)
+            table[0::2] = self.smallest[level]
+            table[1::2] = self.largest[level]
+            self._table[level] = table
+        return self._table[level]
+
     def overlap_ranges(self, level: int, lo: np.ndarray, hi: np.ndarray
                        ) -> tuple[np.ndarray, np.ndarray]:
         """Per-query position slices [start, end) of the level's SSTs
-        intersecting [lo_i, hi_i] (requires lo <= hi elementwise)."""
-        starts = _rank(self.largest[level], lo, "left", self.backend)
-        ends = _rank(self.smallest[level], hi, "right", self.backend)
-        return starts, ends
+        intersecting [lo_i, hi_i] (requires lo <= hi elementwise): one
+        inclusive rank of ``lo - 1`` and ``hi`` over the fence table."""
+        lo = np.asarray(lo, np.int64)
+        r = _rank(self.fence_table(level),
+                  np.concatenate([lo - 1, np.asarray(hi, np.int64)]),
+                  "right", self.backend)
+        n = lo.shape[0]
+        return r[:n] // 2, (r[n:] + 1) // 2
 
     def overlap_slice(self, level: int, lo: int, hi: int) -> tuple[int, int]:
         s, e = self.overlap_ranges(level, np.asarray([lo], np.int64),

@@ -110,8 +110,8 @@ def overlapping(ssts: list[SST], lo: int, hi: int) -> list[SST]:
 
     The list-level oracle for the store's manifest queries: the LSM core
     itself routes through ``repro.core.level_index.LevelIndex``, which
-    answers with the same two fence ranks over its flat arrays — the span
-    is [first SST with largest >= lo, first SST with smallest > hi).
+    answers the same span with one rank over its interleaved fence table —
+    the span is [first SST with largest >= lo, first SST with smallest > hi).
     """
     if not ssts:
         return []

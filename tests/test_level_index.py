@@ -70,6 +70,124 @@ def test_backends_agree_on_overlap_queries(n_ssts):
         assert int(counts[i]) == len(want)
 
 
+def _level_from_ranges(ranges):
+    """A level of SSTs with the given inclusive [smallest, largest] key
+    ranges (a one-key SST where they are equal)."""
+    return [SST(np.unique(np.asarray([a, b], np.int64)),
+                np.zeros(1 if a == b else 2, np.int64), 100)
+            for a, b in ranges]
+
+
+#: sorted, disjoint levels whose fences tie or touch: one-key SSTs
+#: (smallest == largest) and adjacent SSTs (largest + 1 == next smallest)
+EDGE_LEVELS = {
+    "one_sst_one_key": [(10, 10)],
+    "one_key_ssts": [(10, 10), (12, 12), (13, 13), (20, 20)],
+    "adjacent": [(10, 14), (15, 15), (16, 30), (31, 40)],
+    "mixed": [(3, 3), (4, 9), (11, 11), (12, 19), (20, 20), (25, 60),
+              (61, 61)],
+}
+
+
+def _edge_queries(ranges):
+    """Points at each fence, at fence +- 1 and below and above the whole
+    level, then every pair of them as a range."""
+    fences = sorted({f for r in ranges for f in r})
+    pts = sorted({p for f in fences for p in (f - 1, f, f + 1)}
+                 | {0, fences[-1] + 5})
+    pairs = [(a, b) for i, a in enumerate(pts) for b in pts[i:]]
+    lo, hi = (np.asarray(c, np.int64) for c in zip(*pairs))
+    return lo, hi
+
+
+def _walk(ssts, lo, hi):
+    """The list-walking oracle: every SST whose range meets [lo, hi]."""
+    return [s for s in ssts if s.largest >= lo and s.smallest <= hi]
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jnp", "pallas"])
+@pytest.mark.parametrize("shape", sorted(EDGE_LEVELS))
+def test_backends_agree_on_fence_edge_queries(shape, backend):
+    """One rank over the interleaved fence table answers both ends of an
+    overlap query exactly where fences tie (one-key SSTs) or touch
+    (adjacent SSTs), at every fence, fence +- 1 and beyond the level, in
+    overlap_ranges, overlap_counts, overlap_slice and overlap_bytes."""
+    ranges = EDGE_LEVELS[shape]
+    ssts = _level_from_ranges(ranges)
+    lo, hi = _edge_queries(ranges)
+    idx = LevelIndex(3)
+    idx.refresh(2, ssts)
+    # level 1 holds the queries as SSTs, for overlap_bytes(1, 2)
+    idx.refresh(1, _level_from_ranges(zip(lo.tolist(), hi.tolist())))
+    level_index.set_backend(backend)
+    try:
+        starts, ends = idx.overlap_ranges(2, lo, hi)
+        counts = idx.overlap_counts(2, lo, hi)
+        obytes = idx.overlap_bytes(1, 2)
+        slices = [idx.overlap_slice(2, int(a), int(b))
+                  for a, b in zip(lo[::7], hi[::7])]
+    finally:
+        level_index.set_backend("numpy")
+    for i in range(lo.shape[0]):
+        want = _walk(ssts, int(lo[i]), int(hi[i]))
+        assert ssts[int(starts[i]):int(ends[i])] == want, (lo[i], hi[i])
+        assert int(counts[i]) == len(want)
+        assert int(obytes[i]) == sum(s.size for s in want)
+    for (a, b), (s, e) in zip(zip(lo[::7], hi[::7]), slices):
+        assert ssts[s:e] == _walk(ssts, int(a), int(b))
+
+
+def _profiled(trace_dir, fn):
+    """Run ``fn`` under a JAX profiler session (the recorder is on only
+    then); return the recorder's counters and the names of the host
+    spans the profiler's trace holds, one entry per span."""
+    import jax
+    from repro import obs
+    obs.reset()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    counters = obs.counters()
+    obs.reset()
+    (path,) = trace_dir.rglob("*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    spans = sorted(e.name for plane in data.planes
+                   if plane.name.startswith("/host:")
+                   for line in plane.lines for e in line.events
+                   if e.name in ("manifest.rank", "fence_rank.call"))
+    return counters, spans
+
+
+def test_an_overlap_query_is_one_fence_rank_round_trip(tmp_path):
+    """On pallas, one overlap_ranges call of n queries is one device call
+    of 2n ranks: both ends come from the same rank of the fence table."""
+    rng = np.random.default_rng(4)
+    idx = LevelIndex(2, backend="pallas")
+    idx.refresh(1, _mk_level(rng, 20))
+    lo, hi = _queries(rng, 37, int(idx.largest[1][-1]))
+    counters, spans = _profiled(
+        tmp_path, lambda: idx.overlap_ranges(1, lo, hi))
+    assert counters["fence_rank.calls"] == 1
+    assert counters["fence_rank.queries"] == 2 * lo.shape[0]
+    assert spans == ["fence_rank.call", "manifest.rank"]
+
+
+def test_an_empty_level_makes_no_fence_rank_call(tmp_path):
+    idx = LevelIndex(3, backend="pallas")
+    lo = np.asarray([0, 5, 100], np.int64)
+    out = {}
+    counters, spans = _profiled(
+        tmp_path, lambda: out.update(r=idx.overlap_ranges(2, lo, lo + 3)))
+    assert "fence_rank.calls" not in counters
+    assert "fence_rank.call" not in spans
+    starts, ends = out["r"]
+    assert starts.tolist() == ends.tolist() == [0, 0, 0]
+
+
 def test_overlap_bytes_matches_bruteforce():
     rng = np.random.default_rng(3)
     src = _mk_level(rng, 12)
