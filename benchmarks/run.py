@@ -1,9 +1,8 @@
 """Benchmark entry point: ``PYTHONPATH=src python -m benchmarks.run``.
 
 Prints ``name,value,derived`` CSV — one section per paper table/figure
-(Figs 1-13, Table 1), plus the distributed-layer wire benchmark.  Use
-``--full`` for the larger op counts, ``--only fig08,fig13`` to select.
-The roofline table is separate: ``python -m benchmarks.roofline``.
+(Figs 1-13, Table 1).  Use ``--full`` for the larger op counts,
+``--only fig08,fig13`` to select.
 """
 
 from __future__ import annotations
@@ -132,9 +131,6 @@ def main() -> None:
     # docs/benchmarks.md)
     from .serving_tail import bench_serving_tail
     bench_serving_tail(120_000 if args.full else 60_000)
-    # distributed wire benchmark (fast, lowering only)
-    from .compression_wire import bench_wire
-    bench_wire()
     print(f"# total {time.time()-t0:.1f}s")
     if args.json:
         import json

@@ -3,8 +3,7 @@ import sys
 from pathlib import Path
 
 # NOTE: do NOT set --xla_force_host_platform_device_count here — smoke
-# tests and benches must see 1 device.  Multi-device tests spawn
-# subprocesses (tests/test_multidevice.py).
+# tests and benches must see 1 device.
 SRC = Path(__file__).resolve().parents[1] / "src"
 if str(SRC) not in sys.path:
     sys.path.insert(0, str(SRC))

@@ -26,7 +26,7 @@ from repro.analysis import (ScheduleSanitizer, ScheduleSanitizerError,
 from repro.analysis import catalog, schemas
 from repro.analysis.contracts import (check_contract_table,
                                       generate_contract_table)
-from repro.analysis.astutil import load_modules
+from repro.analysis.astutil import import_edges, load_modules
 from repro.analysis.findings import load_baseline, write_baseline
 from repro.analysis.schemas import (CSV_FAMILY, check_schema_table,
                                     extract_variants, generate_schema_table,
@@ -123,6 +123,43 @@ def test_repo_is_clean_minus_baseline():
 def test_cli_exits_zero_on_repo():
     res = _run_cli()
     assert res.returncode == 0, res.stdout + res.stderr
+
+
+def _package_of(mod) -> str:
+    """``repro.<top>`` for a source module, or ``repro.kernels.<sub>`` for
+    one inside a kernel subpackage."""
+    parts = mod.rel.split("/")[1:]            # drop "src"
+    keep = 3 if parts[1] == "kernels" and len(parts) > 3 else 2
+    return ".".join(parts[:keep]).removesuffix(".py")
+
+
+def test_every_package_is_reached_from_an_entry_point():
+    """No package of ``repro`` sits outside what the program runs: every
+    top-level package or module, and every kernel subpackage, is imported
+    (at any depth, inside functions too) from an entry point: the chip
+    harness under ``bench/``, ``chip_smoke.py``, ``python -m
+    repro.analysis`` or ``python -m repro.bench_kv.db_bench``."""
+    src = {m.name: m for m in load_modules(ROOT, [ROOT / "src" / "repro"])}
+    todo = [m for m in load_modules(ROOT, [ROOT / "bench",
+                                           ROOT / "chip_smoke.py"])
+            if not m.rel.startswith("bench/tests/")]
+    todo += [src["repro.analysis.__main__"], src["repro.bench_kv.db_bench"]]
+    seen: set[str] = set()
+    while todo:
+        for edge in import_edges(todo.pop(), include_nested=True):
+            for target in (edge.target,
+                           *(f"{edge.target}.{n}" for n in edge.names)):
+                parts = target.split(".")
+                # importing a module runs every package above it too
+                for i in range(2, len(parts) + 1):
+                    name = ".".join(parts[:i])
+                    if name in src and name not in seen:
+                        seen.add(name)
+                        todo.append(src[name])
+    every = {_package_of(m) for m in src.values()}
+    reached = {_package_of(src[n]) for n in seen}
+    missing = sorted(every - reached)
+    assert not missing, f"no entry point reaches {missing}"
 
 
 def test_baseline_suppresses_known_findings(tmp_path):

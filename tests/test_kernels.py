@@ -197,84 +197,6 @@ def test_lindley_scan_batched_ragged():
                                        rtol=1e-12, atol=1e-12)
 
 
-# --------------------------------------------------------- flash_attention
-@pytest.mark.parametrize("b,hq,hkv,s,d,win,dtype", [
-    (1, 2, 2, 256, 64, None, "float32"),
-    (2, 4, 2, 128, 64, None, "float32"),
-    (1, 2, 1, 256, 128, 128, "float32"),
-    (1, 2, 2, 384, 64, None, "bfloat16"),
-    (1, 1, 1, 130, 64, None, "float32"),
-])
-def test_flash_attention(b, hq, hkv, s, d, win, dtype):
-    from repro.kernels.flash_attention import flash_attention
-    from repro.kernels.flash_attention.ref import attention_ref
-    rng = np.random.default_rng(42)
-    dt = jnp.dtype(dtype)
-    q = jnp.asarray(rng.standard_normal((b, hq, s, d)), dt)
-    k = jnp.asarray(rng.standard_normal((b, hkv, s, d)), dt)
-    v = jnp.asarray(rng.standard_normal((b, hkv, s, d)), dt)
-    got = flash_attention(q, k, v, causal=True, window=win)
-    ref = attention_ref(q, k, v, causal=True, window=win)
-    tol = 3e-2 if dtype == "bfloat16" else 2e-5
-    assert float(jnp.max(jnp.abs(got.astype(jnp.float32)
-                                 - ref.astype(jnp.float32)))) < tol
-
-
-# --------------------------------------------------------- paged_attention
-@pytest.mark.parametrize("b,hq,hkv,d,npg,ps,maxp,dtype", [
-    (2, 4, 2, 64, 16, 16, 4, "float32"),
-    (1, 8, 1, 128, 32, 32, 8, "float32"),
-    (3, 4, 4, 64, 8, 16, 3, "bfloat16"),
-])
-def test_paged_attention(b, hq, hkv, d, npg, ps, maxp, dtype):
-    from repro.kernels.paged_attention import paged_attention
-    from repro.kernels.paged_attention.ref import paged_attention_ref
-    rng = np.random.default_rng(7)
-    dt = jnp.dtype(dtype)
-    q = jnp.asarray(rng.standard_normal((b, hq, d)), dt)
-    kp = jnp.asarray(rng.standard_normal((npg, ps, hkv, d)), dt)
-    vp = jnp.asarray(rng.standard_normal((npg, ps, hkv, d)), dt)
-    pt = jnp.asarray(rng.integers(0, npg, (b, maxp)), jnp.int32)
-    ln = jnp.asarray(rng.integers(1, maxp * ps + 1, (b,)), jnp.int32)
-    got = paged_attention(q, kp, vp, pt, ln)
-    ref = paged_attention_ref(q, kp, vp, pt, ln)
-    tol = 5e-2 if dtype == "bfloat16" else 2e-5
-    assert float(jnp.max(jnp.abs(got.astype(jnp.float32)
-                                 - ref.astype(jnp.float32)))) < tol
-
-
-# ---------------------------------------------------------------- ssd_scan
-@pytest.mark.parametrize("b,L,h,g,p,n,ck,dtype", [
-    (1, 128, 2, 1, 64, 64, 64, "float32"),
-    (2, 256, 4, 2, 32, 16, 128, "float32"),
-    (1, 200, 2, 1, 64, 32, 64, "float32"),
-    (1, 128, 2, 1, 64, 64, 64, "bfloat16"),
-])
-def test_ssd_scan(b, L, h, g, p, n, ck, dtype):
-    from repro.kernels.ssd_scan import ssd_scan
-    from repro.kernels.ssd_scan.ref import ssd_scan_ref
-    rng = np.random.default_rng(4)
-    dt = jnp.dtype(dtype)
-    x = jnp.asarray(rng.standard_normal((b, L, h, p)), dt)
-    dts = jnp.asarray(np.abs(rng.standard_normal((b, L, h))) * 0.1 + 0.01, dt)
-    a = jnp.asarray(-np.abs(rng.standard_normal(h)) - 0.1, jnp.float32)
-    bb = jnp.asarray(rng.standard_normal((b, L, g, n)) * 0.3, dt)
-    cc = jnp.asarray(rng.standard_normal((b, L, g, n)) * 0.3, dt)
-    got = ssd_scan(x, dts, a, bb, cc, ck=ck)
-    rep = h // g
-    bf = jnp.repeat(bb, rep, axis=2); cf = jnp.repeat(cc, rep, axis=2)
-    ref = ssd_scan_ref(
-        x.transpose(0, 2, 1, 3).reshape(b * h, L, p),
-        dts.transpose(0, 2, 1).reshape(b * h, L),
-        jnp.tile(a, b),
-        bf.transpose(0, 2, 1, 3).reshape(b * h, L, n),
-        cf.transpose(0, 2, 1, 3).reshape(b * h, L, n),
-    ).reshape(b, h, L, p).transpose(0, 2, 1, 3)
-    tol = 6e-2 if dtype == "bfloat16" else 2e-4
-    assert float(jnp.max(jnp.abs(got.astype(jnp.float32)
-                                 - ref.astype(jnp.float32)))) < tol
-
-
 # ---------------------------------------------------------------- platform
 def test_interpret_mode_follows_the_platform():
     from repro.kernels.platform import interpret_mode

@@ -1,8 +1,4 @@
-"""End-to-end behaviour tests: the paper's headline claims, the training
-loop with failure injection, and the serving loop."""
-
-import numpy as np
-import pytest
+"""End-to-end behaviour tests: the paper's headline claims."""
 
 from repro.bench_kv import make_load_a, run_ycsb, sustainable_throughput
 from repro.core import LSMConfig
@@ -40,27 +36,3 @@ def test_phi64_failure_mode():
     f64 = r64.sim.stats.vssts_good / max(
         1, r64.sim.stats.vssts_good + r64.sim.stats.vssts_poor)
     assert f32 > f64
-
-
-@pytest.mark.xfail(
-    reason="pre-existing seed failure: at smoke scale (24 steps, batch 4) "
-           "the loss-decrease assertion sits at noise level (~6.2604 vs "
-           "~6.2577 — a 0.04% gap); the restart/restore machinery it "
-           "exercises passes, only the progress check is flaky",
-    strict=False)
-def test_train_loop_with_failure_and_restore(tmp_path):
-    from repro.launch.train import run
-    out = run("qwen3_1_7b", smoke=True, steps=24, batch=4, seq=32,
-              ckpt_every=8, ckpt_dir=tmp_path, fail_at=18, log_every=100)
-    assert out["restarts"] == 1
-    assert np.isfinite(out["losses"]).all()
-    # training makes progress on the learnable synthetic stream
-    assert np.mean(out["losses"][-5:]) < np.mean(out["losses"][:5])
-
-
-def test_serve_loop_prefix_hits():
-    from repro.launch.serve import run
-    out = run("gemma3_1b", smoke=True, n_requests=6, decode_tokens=4)
-    s = out["stats"]
-    assert s["prefix_hits"] >= 3          # shared prefixes hit after warmup
-    assert all(len(o) == 4 for o in out["outputs"])
